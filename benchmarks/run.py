@@ -43,6 +43,8 @@ from __future__ import annotations
 import json
 import sys
 
+from repro.runtime.compile_cache import enable_compile_cache
+
 from .common import stamp_json
 from .paper_tables import (
     table1_full_pipeline,
@@ -53,7 +55,6 @@ from .paper_tables import (
     table7_speedup_matrix,
     table_fused_roofline,
 )
-from .t5_dp_scaling import table5_dp_scaling
 
 
 def _stamp_file(path: str) -> None:
@@ -74,6 +75,7 @@ def _stamp_file(path: str) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     quick = "--quick" in sys.argv
     summary = {}
 
@@ -348,10 +350,6 @@ def main() -> None:
     t3 = table3_stage_split()
     summary["canny_share"] = t3["canny_share"]
 
-    if not quick:
-        t5 = table5_dp_scaling((1, 2, 4))
-        summary["dp_scaling"] = t5["scaling_at_max"]
-
     t6 = table6_core_paths()
     summary["t6_canny_speedup"] = t6["canny_speedup"]
     summary["t6_hough_speedup"] = t6["hough_speedup"]
@@ -380,15 +378,6 @@ def main() -> None:
     print(f"  canny share of detection (paper: 87.6% scalar): "
           f"{summary['canny_share']:.0%} here (canny already vectorized; "
           f"the scatter-bound Hough dominates a CPU)")
-    if "dp_scaling" in summary:
-        import os
-        cores = os.cpu_count() or 1
-        note = (" — NOTE: this host has 1 physical core, so virtual "
-                "devices time-share and wall-clock cannot scale; the "
-                "table verifies correctness of the pmap program, the "
-                "paper's 2x needs 2 real cores" if cores == 1 else "")
-        print(f"  DP scaling (paper: ~2x on 2 cores): "
-              f"{summary['dp_scaling']:.2f}x on 4 devices{note}")
     print(f"  projected total speedup, VPU-only vs MXU-offload on TPU v5e "
           f"(paper: 3.7x vs Rocket): "
           f"{summary['projected_total_speedup']:.2f}x")

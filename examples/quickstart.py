@@ -11,9 +11,11 @@ import numpy as np
 
 from repro.core import CannyConfig, LineDetector, PipelineConfig
 from repro.data.images import synthetic_road
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=None, help="write rendered PNG here")
     ap.add_argument("--integer", action="store_true",

@@ -6,6 +6,8 @@
 import sys
 
 from repro.launch.serve import main
+from repro.runtime.compile_cache import enable_compile_cache
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main(sys.argv[1:])

@@ -16,8 +16,10 @@ prefetching resumable data, async checkpoints, resume.
 import sys
 
 from repro.launch.train import main
+from repro.runtime.compile_cache import enable_compile_cache
 
 if __name__ == "__main__":
+    enable_compile_cache()
     argv = sys.argv[1:]
     if not any(a.startswith("--steps") for a in argv):
         argv += ["--steps", "200"]

@@ -46,6 +46,7 @@ from repro.core import (
 )
 from repro.core.lines import render_lines
 from repro.data import scenario_names, scenario_stream, standard_drive_cycle
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def serve_with_tracking(args, cfg: PipelineConfig) -> None:
@@ -162,6 +163,7 @@ def serve_with_deadlines(args, cfg: PipelineConfig) -> None:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--frames", type=int, default=16)
     ap.add_argument("--height", type=int, default=240)

@@ -112,10 +112,15 @@ class CannyConfig:
 def gradient_masks(cfg: CannyConfig) -> tuple[np.ndarray, ...]:
     """The conv-mask constants ``_gradients`` needs for ``cfg``, in order.
 
-    Exposed so the fused detection kernel can feed the masks in as Pallas
-    operands (kernel bodies may not capture array constants) via the
-    ``masks=`` override on :func:`canny` — the override is positional and
-    must come from this function for the same ``cfg``.
+    Exposed so the fused detection kernel computes its taps from the same
+    constants.  The f32 tier gets integer-valued masks, so on integer-valued
+    frames (every uint8 camera frame) each conv sum is an exact integer in
+    f32 -- below 2**24 for 0..255 inputs -- and the gradients are
+    bit-identical on every backend and in every summation order: XLA on
+    the CPU, the MXU at HIGHEST precision, the VPU tap sums of the fused
+    kernel.  ``thresholds`` folds the Gaussian's 1/GAUSS_NORM into the
+    Canny thresholds instead.  The f16 and int8 tiers keep the normalized
+    Gaussian they were tuned with.
     """
     if cfg.integer or cfg.grad_dtype == "int8":
         if cfg.fused:
@@ -124,25 +129,19 @@ def gradient_masks(cfg: CannyConfig) -> tuple[np.ndarray, ...]:
             GAUSS_5x5.astype(np.int32)[None],
             np.stack([SOBEL_X, SOBEL_Y]).astype(np.int32),
         )
-    dt = np.float16 if cfg.grad_dtype == "f16" else np.float32
+    if cfg.grad_dtype == "f16":
+        if cfg.fused:
+            return (fused_masks().astype(np.float16),)
+        return (
+            (GAUSS_5x5 / GAUSS_NORM)[None].astype(np.float16),
+            np.stack([SOBEL_X, SOBEL_Y]).astype(np.float16),
+        )
     if cfg.fused:
-        return (fused_masks().astype(dt),)
-    return (
-        (GAUSS_5x5 / GAUSS_NORM)[None].astype(dt),
-        np.stack([SOBEL_X, SOBEL_Y]).astype(dt),
-    )
+        return (np.round(fused_masks() * GAUSS_NORM).astype(np.float32),)
+    return (GAUSS_5x5[None], np.stack([SOBEL_X, SOBEL_Y]))
 
 
-def _gradients(image: jax.Array, cfg: CannyConfig, masks=None):
-    """Stages 1-2: noise reduction + intensity gradient, all GEMM-form.
-
-    ``image`` is (..., H, W); conv outputs stack masks on axis -3.
-    ``masks`` optionally overrides the conv-mask constants (must match
-    ``gradient_masks(cfg)`` positionally — the fused-kernel seam).
-    Whatever the accumulation tier, ``gx``/``gy`` come back as f32 (int32
-    for the paper's integer rewrite) so the threshold compare downstream
-    is always full-precision.
-    """
+def _check_grad_tier(cfg: CannyConfig) -> None:
     if cfg.grad_dtype not in ("f32", "f16", "int8"):
         raise ValueError(f"unknown grad_dtype {cfg.grad_dtype!r}")
     if cfg.integer and cfg.grad_dtype != "f32":
@@ -150,8 +149,19 @@ def _gradients(image: jax.Array, cfg: CannyConfig, masks=None):
             "grad_dtype tiers apply to the float pipeline; the integer "
             "rewrite (integer=True) is its own arithmetic mode"
         )
-    if masks is None:
-        masks = tuple(jnp.asarray(m) for m in gradient_masks(cfg))
+
+
+def _gradients(image: jax.Array, cfg: CannyConfig):
+    """Stages 1-2: noise reduction + intensity gradient, all GEMM-form.
+
+    ``image`` is (..., H, W); conv outputs stack masks on axis -3.
+    Whatever the accumulation tier, ``gx``/``gy`` come back as f32 (int32
+    for the paper's integer rewrite) so the threshold compare downstream
+    is always full-precision.  The f32 tier returns the raw integer-mask
+    sums, GAUSS_NORM times the normalized values (see ``gradient_masks``).
+    """
+    _check_grad_tier(cfg)
+    masks = tuple(jnp.asarray(m) for m in gradient_masks(cfg))
 
     if cfg.integer:
         img = image.astype(jnp.int32)
@@ -211,25 +221,42 @@ def _gradients(image: jax.Array, cfg: CannyConfig, masks=None):
     img = image.astype(jnp.float32)
     if cfg.fused:
         out = ops.conv2d_gemm(img, masks[0], impl=cfg.impl)
-        return out[..., 0, :, :], out[..., 1, :, :], out[..., 2, :, :]
-    nr = ops.conv2d_gemm(img, masks[0], impl=cfg.impl)[..., 0, :, :]
-    gxy = ops.conv2d_gemm(nr, masks[1], impl=cfg.impl)
-    return nr, gxy[..., 0, :, :], gxy[..., 1, :, :]
+        s, gx, gy = out[..., 0, :, :], out[..., 1, :, :], out[..., 2, :, :]
+    else:
+        s = ops.conv2d_gemm(img, masks[0], impl=cfg.impl)[..., 0, :, :]
+        gxy = ops.conv2d_gemm(s, masks[1], impl=cfg.impl)
+        gx, gy = gxy[..., 0, :, :], gxy[..., 1, :, :]
+    return s, gx, gy
+
+
+def _sum_squares(gx, gy):
+    """``gx*gx + gy*gy`` in f32, with the same bits on every backend.
+
+    XLA on the CPU contracts ``a*a + b*b`` into a fused multiply-add, and a
+    backend that does not rounds differently.  Each gradient is split as
+    ``h + l`` with ``h`` a multiple of 512: on integer-valued gradients
+    below 2**18 (the f32 tier's raw conv sums) every product and the two
+    inner sums are exact, so contraction changes nothing and only the last
+    two adds round, in this fixed order.
+    """
+    hx, hy = (jnp.floor(g * (1.0 / 512) + 0.5) * 512 for g in (gx, gy))
+    lx, ly = gx - hx, gy - hy
+    return ((hx * hx + hy * hy) + 2 * (hx * lx + hy * ly)) + (
+        lx * lx + ly * ly)
 
 
 def _magnitude_direction(gx, gy, integer: bool):
-    """Stage 2b: |G| and direction bin in {0, 45, 90, 135} (VPU work)."""
+    """Stage 2b: |G| and direction bin in {0, 45, 90, 135} (VPU work).
+
+    The integer pipeline takes the L1 magnitude; the float pipeline the
+    squared L2 magnitude (``thresholds`` squares the Canny thresholds to
+    match: no sqrt, which a TPU does not round correctly).  Both test the
+    direction by cross-multiplied tan ratios (no arctan, no divide).
+    """
     ax, ay = jnp.abs(gx), jnp.abs(gy)
-    if integer:
-        mag = ax + ay  # L1 magnitude: no sqrt in the int pipeline
-        # direction via cross-multiplied tan thresholds (no arctan):
-        d0 = TAN_22_DEN * ay < TAN_22_NUM * ax            # ~horizontal grad
-        d90 = TAN_67_DEN * ay >= TAN_67_NUM * ax          # ~vertical grad
-    else:
-        mag = jnp.sqrt(gx * gx + gy * gy)
-        t = ay / jnp.maximum(ax, 1e-9)
-        d0 = t < (TAN_22_NUM / TAN_22_DEN)
-        d90 = t >= (TAN_67_NUM / TAN_67_DEN)
+    mag = ax + ay if integer else _sum_squares(gx, gy)
+    d0 = TAN_22_DEN * ay < TAN_22_NUM * ax            # ~horizontal grad
+    d90 = TAN_67_DEN * ay >= TAN_67_NUM * ax          # ~vertical grad
     diag = jnp.logical_not(d0 | d90)
     same_sign = (gx >= 0) == (gy >= 0)
     # bins: 0 => E-W neighbour pair, 1 => NE-SW, 2 => N-S, 3 => NW-SE
@@ -239,73 +266,95 @@ def _magnitude_direction(gx, gy, integer: bool):
     return mag, dirs
 
 
+def thresholds(cfg: CannyConfig) -> tuple[float, float]:
+    """(low, high) in the units of ``_magnitude_direction``'s magnitude."""
+    if cfg.integer:
+        return cfg.low, cfg.high
+    # The f32 tier's gradients are raw conv sums, GAUSS_NORM x intensity.
+    unit = GAUSS_NORM if cfg.grad_dtype == "f32" else 1.0
+    return (cfg.low * unit) ** 2, (cfg.high * unit) ** 2
+
+
 def _shift(x, dy, dx):
-    """Zero-padded spatial shift over the trailing (H, W) axes."""
+    """Zero-filled spatial shift over the trailing (H, W) axes:
+    ``out[i, j] = x[i + dy, j + dx]``."""
     H, W = x.shape[-2:]
     pad = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(1, 1), (1, 1)])
     return pad[..., 1 + dy : 1 + dy + H, 1 + dx : 1 + dx + W]
 
 
-def _nms(mag, dirs):
+def _nms(mag, dirs, shift):
     """Direction-aware non-max suppression (full variant, stage 3)."""
     pairs = [((0, 1), (0, -1)), ((-1, 1), (1, -1)),
              ((1, 0), (-1, 0)), ((1, 1), (-1, -1))]
     keep = jnp.zeros_like(mag, dtype=bool)
     for b, (p, q) in enumerate(pairs):
-        n1 = _shift(mag, *p)
-        n2 = _shift(mag, *q)
+        n1 = shift(mag, *p)
+        n2 = shift(mag, *q)
         keep = keep | ((dirs == b) & (mag >= n1) & (mag >= n2))
     return jnp.where(keep, mag, 0)
 
 
-def _dilate3(x):
-    out = x
-    for dy in (-1, 0, 1):
-        for dx in (-1, 0, 1):
-            if dy or dx:
-                out = out | _shift(x, dy, dx)
-    return out
+def _dilate3(x, shift):
+    """3x3 binary dilation, separably: a row pass, then a column pass."""
+    rows = x | shift(x, 0, -1) | shift(x, 0, 1)
+    return rows | shift(rows, -1, 0) | shift(rows, 1, 0)
 
 
-def _clear_border(x: jax.Array, b: int) -> jax.Array:
-    if b <= 0:
+def _clear_border(x: jax.Array, b: int, height: int, width: int
+                  ) -> jax.Array:
+    """Zero ``x`` outside ``[b, height - b) x [b, width - b)``."""
+    if b <= 0 and (height, width) == x.shape[-2:]:
         return x
-    H, W = x.shape[-2:]
-    yy = jnp.arange(H)[:, None]
-    xx = jnp.arange(W)[None, :]
-    inside = (yy >= b) & (yy < H - b) & (xx >= b) & (xx < W - b)
+    yy = jax.lax.broadcasted_iota(jnp.int32, x.shape[-2:], 0)
+    xx = jax.lax.broadcasted_iota(jnp.int32, x.shape[-2:], 1)
+    inside = (yy >= b) & (yy < height - b) & (xx >= b) & (xx < width - b)
     return jnp.where(inside, x, jnp.zeros_like(x))
 
 
-def canny(image: jax.Array, cfg: CannyConfig = CannyConfig(),
-          masks=None) -> jax.Array:
+def edge_mask(gx, gy, cfg: CannyConfig, *, shift=_shift, region=None,
+              carry=jnp.bool_):
+    """Stages 2b-5 on gradients: the boolean Canny edge map.
+
+    ``shift`` is the zero-filled neighbour shift (``_shift`` here; the
+    fused kernel passes a lane/sublane rotate).  ``region`` is the
+    ``(height, width)`` of the real frame when ``gx`` is padded beyond it;
+    everything outside it is cleared like the border.  ``carry`` is the
+    dtype of the hysteresis loop's carry: the fused kernel passes int32,
+    since the TPU compiler cannot carry a boolean vector.
+    """
+    height, width = region or gx.shape[-2:]
+    mag, dirs = _magnitude_direction(gx, gy, cfg.integer)
+    mag = _clear_border(mag, cfg.border, height, width)
+    low, high = thresholds(cfg)
+
+    if cfg.variant == "paper":
+        # Algorithm 1 stages 3-5: pure thresholds, one hysteresis pass.
+        edge = mag >= low
+        strong = edge & (mag >= high)
+        return strong | (edge & _dilate3(strong, shift))
+
+    sup = _nms(mag, dirs, shift)
+    strong = sup >= high
+    weak = (sup >= low) & ~strong
+
+    def body(_, s):
+        s = s.astype(bool)
+        return (s | (weak & _dilate3(s, shift))).astype(carry)
+
+    grown = jax.lax.fori_loop(0, cfg.hysteresis_iters, body,
+                              strong.astype(carry))
+    return grown.astype(bool)
+
+
+def canny(image: jax.Array, cfg: CannyConfig = CannyConfig()) -> jax.Array:
     """Edge map (..., H, W) uint8 in {0, 255} (paper's ``image_out``).
 
     Accepts a single frame (H, W) or a batch (N, H, W) — the batch lowers
     through the conv kernel as one launch and the VPU stages broadcast.
-    ``masks`` optionally overrides the gradient conv masks (positional per
-    ``gradient_masks(cfg)``) so a Pallas caller can pass them as operands.
     """
-    nr, gx, gy = _gradients(image, cfg, masks)
-    mag, dirs = _magnitude_direction(gx, gy, cfg.integer)
-    mag = _clear_border(mag, cfg.border)
-
-    if cfg.variant == "paper":
-        # Algorithm 1 stages 3-5: pure thresholds, one hysteresis pass.
-        edge = (mag >= cfg.low)
-        strong = edge & (mag >= cfg.high)
-        out = strong | (edge & _dilate3(strong))
-        return jnp.where(out, 255, 0).astype(jnp.uint8)
-
-    sup = _nms(mag, dirs)
-    strong = sup >= cfg.high
-    weak = (sup >= cfg.low) & ~strong
-
-    def body(_, s):
-        return s | (weak & _dilate3(s))
-
-    strong = jax.lax.fori_loop(0, cfg.hysteresis_iters, body, strong)
-    return jnp.where(strong, 255, 0).astype(jnp.uint8)
+    _, gx, gy = _gradients(image, cfg)
+    return jnp.where(edge_mask(gx, gy, cfg), 255, 0).astype(jnp.uint8)
 
 
 canny_jit = jax.jit(canny, static_argnames=("cfg",))
@@ -314,8 +363,7 @@ canny_jit = jax.jit(canny, static_argnames=("cfg",))
 @functools.partial(jax.jit, static_argnames=("cfg", "stride", "margin"))
 def estimate_edge_count_device(image: jax.Array,
                                cfg: CannyConfig = CannyConfig(), *,
-                               stride: int = 2, margin: float = 2.5,
-                               corridors: jax.Array | None = None
+                               stride: int = 2, margin: float = 2.5
                                ) -> jax.Array:
     """Device-side downsampled-gradient edge-count bound (int32 scalar).
 
@@ -334,17 +382,8 @@ def estimate_edge_count_device(image: jax.Array,
     # low/2, floored at 20: contrast below that never survives the double
     # threshold, and 20 sits >3 sigma above asphalt-texture differences so
     # the count tracks strokes/speckle, not ground-plane noise.
-    #
-    # ``corridors`` makes the bound corridor-aware for the fused path's
-    # tier selection: coarse hits outside every (widened) rho window don't
-    # count, since the fused kernel drops those pixels before compaction.
-    # The windows are widened by 2*stride — the worst-case rho drift
-    # between a coarse cell corner and any fine pixel it represents is
-    # stride*sqrt(2) — so the estimate stays an upper bound.
     thresh = max(cfg.low / 2.0, 20.0)
-    hits = ops.grad_hits(image, stride=stride, thresh=thresh,
-                         corridors=corridors, widen=2.0 * stride,
-                         impl=cfg.impl)
+    hits = ops.grad_hits(image, stride=stride, thresh=thresh, impl=cfg.impl)
     worst = hits.max().astype(jnp.float32)
     return jnp.floor(worst * stride * margin).astype(jnp.int32) + 64
 

@@ -276,17 +276,7 @@ def _hough_transform(edges: jax.Array, cfg: HoughConfig = HoughConfig(),
     ``cfg.theta_band``/``theta_bins`` restrict the sweep to the prediction
     gate (the accumulator stays full width, zero outside the gate).
     """
-    if (theta_bins is None) != (cfg.theta_band is None):
-        raise ValueError(
-            "HoughConfig.theta_band and the theta_bins argument come as a "
-            f"pair (got theta_band={cfg.theta_band!r}, "
-            f"theta_bins={'set' if theta_bins is not None else None!r})."
-        )
-    if theta_bins is not None and theta_bins.shape != (cfg.theta_band,):
-        raise ValueError(
-            f"theta_bins must have the plan's static band shape "
-            f"({cfg.theta_band},); got {theta_bins.shape}."
-        )
+    _check_gate(theta_bins, cfg)
     H, W = edges.shape[-2:]
     n_rho = rho_bins(H, W, cfg)
     trig = hough_trig(H, W, cfg)
@@ -319,39 +309,7 @@ def _check_corridors(corridors, cfg: HoughConfig) -> None:
         )
 
 
-def fused_hough(image: jax.Array, canny_cfg, cfg: HoughConfig,
-                theta_bins: jax.Array | None = None,
-                corridors: jax.Array | None = None, *,
-                scatter: bool = True) -> jax.Array:
-    """The fused hot path: image -> votes with no HBM round trips between.
-
-    Kernel A (``ops.fused_detect``) runs the whole Canny front end,
-    corridor-filters, and compacts in VMEM; kernel B is the standard vote
-    over the compacted list.  Bit-exact with ``canny`` + ``hough_transform``
-    at full corridor/band coverage whenever the edge count fits the
-    compaction buffer (votes are small-integer sums in f32 and both paths
-    produce the identical edge set).
-
-    ``cfg.max_edges`` must be a resolved int (or None for the dense
-    default): the fused path never materializes an edge map to count, so
-    ``"auto"`` only exists in tiered form (``fused_hough_tiered``).
-    """
-    if cfg.max_edges == "auto":
-        raise ValueError(
-            "fused_hough cannot resolve max_edges='auto' (there is no "
-            "edge map to count); use fused_hough_tiered."
-        )
-    return _fused_hough(image, canny_cfg, cfg, theta_bins, corridors,
-                        scatter=scatter)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("canny_cfg", "cfg", "scatter")
-)
-def _fused_hough(image: jax.Array, canny_cfg, cfg: HoughConfig,
-                 theta_bins: jax.Array | None = None,
-                 corridors: jax.Array | None = None, *,
-                 scatter: bool = True) -> jax.Array:
+def _check_gate(theta_bins, cfg: HoughConfig) -> None:
     if (theta_bins is None) != (cfg.theta_band is None):
         raise ValueError(
             "HoughConfig.theta_band and the theta_bins argument come as a "
@@ -363,22 +321,36 @@ def _fused_hough(image: jax.Array, canny_cfg, cfg: HoughConfig,
             f"theta_bins must have the plan's static band shape "
             f"({cfg.theta_band},); got {theta_bins.shape}."
         )
-    _check_corridors(corridors, cfg)
+
+
+def fused_hough(image: jax.Array, canny_cfg, cfg: HoughConfig,
+                theta_bins: jax.Array | None = None,
+                corridors: jax.Array | None = None, *,
+                scatter: bool = True) -> jax.Array:
+    """The fused hot path: image -> votes with no edge map in HBM.
+
+    Kernel A (``ops.fused_weights``) runs the whole Canny front end,
+    thresholds and corridor-filters in VMEM; the raster compaction and
+    kernel B (the standard vote over the compacted list) follow.
+    Bit-exact with ``canny`` + ``hough_transform`` at full corridor/band
+    coverage whenever the edge count fits the compaction buffer (votes are
+    small-integer sums in f32 and both paths produce the identical edge
+    set).
+
+    ``cfg.max_edges`` must be a resolved int (or None for the dense
+    default); ``"auto"`` exists in tiered form (``fused_hough_tiered``).
+    """
+    if cfg.max_edges == "auto":
+        raise ValueError(
+            "fused_hough cannot resolve max_edges='auto'; use "
+            "fused_hough_tiered."
+        )
     H, W = image.shape[-2:]
-    n_rho = rho_bins(H, W, cfg)
     max_edges = cfg.max_edges
     if max_edges is None:
         max_edges = ops.default_max_edges(H * W)
-    cxy, cw = ops.fused_detect(
-        image, corridors, cfg=canny_cfg,
-        edge_threshold=cfg.edge_threshold, max_edges=max_edges,
-        impl=cfg.impl,
-    )
-    return ops.hough_vote(
-        cxy, cw, jnp.asarray(hough_trig(H, W, cfg)), n_rho=n_rho,
-        impl=cfg.impl, compact=False, theta_bins=theta_bins,
-        scatter_back=scatter,
-    )
+    return _fused_hough_tiered(image, canny_cfg, cfg, (int(max_edges),),
+                               theta_bins, corridors, scatter=scatter)
 
 
 def fused_hough_tiered(image: jax.Array, canny_cfg, cfg: HoughConfig,
@@ -388,91 +360,41 @@ def fused_hough_tiered(image: jax.Array, canny_cfg, cfg: HoughConfig,
                        scatter: bool = True) -> jax.Array:
     """Tiered ``max_edges`` dispatch for the fused path (trace-safe).
 
-    Two tier selectors, split by where the buffer size must be known:
-
-    * **Host backends (xla/stencil):** the whole fused module — Canny,
-      corridor filter, exact count, compaction, vote — is one jitted
-      program.  The weights exist as an in-module intermediate, so the
-      selector counts them *exactly* (post-corridor, max over a batch)
-      and ``lax.switch``es over compact+vote branches, just like the
-      staged ``hough_transform_tiered``.  Same count ⇒ same tier as
-      staged at full coverage, and corridors genuinely shrink the tier
-      on cluttered frames.
-    * **Pallas (pallas/interpret):** kernel A's compaction buffer is an
-      output shape fixed before launch, so the tier comes from the
-      *pre-Canny* downsampled-gradient bound
-      (``canny.estimate_edge_count_device``), made corridor-aware.  The
-      estimate is an upper bound (validated per scenario family), so it
-      over-provisions — a larger-than-needed tier votes zero rows and
-      stays bit-exact.
-
-    Either way only a genuine overflow of the cap tier drops edges,
-    exactly like the staged cap.
+    Kernel A emits the thresholded, corridor-filtered weights, so the tier
+    selector counts the surviving edges *exactly* (max over a batch) and
+    ``lax.switch``es over compact+vote branches, just like the staged
+    ``hough_transform_tiered``: same count, same tier as staged at full
+    coverage, and corridors genuinely shrink the tier on cluttered frames.
+    Only a genuine overflow of the cap tier drops edges, exactly like the
+    staged cap.
     """
-    if not cfg.compact:
-        return _fused_hough(
-            image, canny_cfg, dataclasses.replace(cfg, max_edges=None),
-            theta_bins, corridors, scatter=scatter,
-        )
     H, W = image.shape[-2:]
-    if tiers is None:
+    if not cfg.compact:
+        tiers = (ops.default_max_edges(H * W),)
+    elif tiers is None:
         tiers = max_edge_tiers(H, W)
-    if ops.resolve_impl(cfg.impl) in ("xla", "stencil"):
-        return _fused_hough_tiered_exact(
-            image, canny_cfg, cfg, tuple(tiers), theta_bins, corridors,
-            scatter=scatter,
-        )
-    # function-level: plan imports both (and the package re-exports the
-    # ``canny`` *function*, so import the module by its full path)
-    from .canny import estimate_edge_count_device
-
-    est = estimate_edge_count_device(image, canny_cfg, corridors=corridors)
-    idx = jnp.minimum(
-        sum((est > t).astype(jnp.int32) for t in tiers),
-        len(tiers) - 1,
-    )
-    cfgs = [dataclasses.replace(cfg, max_edges=int(t)) for t in tiers]
-
-    def make(c):
-        # theta_bins/corridors captured by closure (lax.switch branches may
-        # close over tracers) so every branch keeps one operand signature.
-        def branch(img):
-            return _fused_hough(img, canny_cfg, c, theta_bins, corridors,
-                                scatter=scatter)
-
-        return branch
-
-    return jax.lax.switch(idx, [make(c) for c in cfgs], image)
+    return _fused_hough_tiered(image, canny_cfg, cfg, tuple(tiers),
+                               theta_bins, corridors, scatter=scatter)
 
 
 @functools.partial(
     jax.jit, static_argnames=("canny_cfg", "cfg", "tiers", "scatter")
 )
-def _fused_hough_tiered_exact(image: jax.Array, canny_cfg, cfg: HoughConfig,
-                              tiers: tuple[int, ...],
-                              theta_bins: jax.Array | None = None,
-                              corridors: jax.Array | None = None, *,
-                              scatter: bool = True) -> jax.Array:
-    """Exact-count fused tiering for host backends: one module end to end.
+def _fused_hough_tiered(image: jax.Array, canny_cfg, cfg: HoughConfig,
+                        tiers: tuple[int, ...],
+                        theta_bins: jax.Array | None = None,
+                        corridors: jax.Array | None = None, *,
+                        scatter: bool = True) -> jax.Array:
+    """Kernel A, exact-count tier choice, raster compaction, kernel B.
 
-    Canny runs once; the exact post-corridor edge count (the same
-    reduction as ``hough_transform_tiered``, on weights instead of the
-    edge map) picks the branch; each branch compacts via the raster
-    index scatter and votes.  Bit-exact with the staged path at full
-    corridor/band coverage because the count — hence the tier — matches
-    the staged dispatch and compaction preserves raster order.
+    The exact post-corridor edge count (the same reduction as
+    ``hough_transform_tiered``, on weights instead of the edge map) picks
+    the branch; each branch compacts via the raster index scatter and
+    votes.  Bit-exact with the staged path at full corridor/band coverage
+    because the count — hence the tier — matches the staged dispatch and
+    compaction preserves raster order.
     """
-    if (theta_bins is None) != (cfg.theta_band is None):
-        raise ValueError(
-            "HoughConfig.theta_band and the theta_bins argument come as a "
-            f"pair (got theta_band={cfg.theta_band!r}, "
-            f"theta_bins={'set' if theta_bins is not None else None!r})."
-        )
-    if theta_bins is not None and theta_bins.shape != (cfg.theta_band,):
-        raise ValueError(
-            f"theta_bins must have the plan's static band shape "
-            f"({cfg.theta_band},); got {theta_bins.shape}."
-        )
+    _check_gate(theta_bins, cfg)
     _check_corridors(corridors, cfg)
     H, W = image.shape[-2:]
     n_rho = rho_bins(H, W, cfg)
@@ -491,9 +413,7 @@ def _fused_hough_tiered_exact(image: jax.Array, canny_cfg, cfg: HoughConfig,
         # theta_bins captured by closure (lax.switch branches may close
         # over tracers) so every branch keeps one operand signature.
         def branch(w):
-            cxy, cw = ops.compact_raster(
-                w, width=W, max_edges=int(t), impl=cfg.impl
-            )
+            cxy, cw = ops.compact_raster(w, width=W, max_edges=int(t))
             return ops.hough_vote(
                 cxy, cw, trig, n_rho=n_rho, impl=cfg.impl, compact=False,
                 theta_bins=theta_bins, scatter_back=scatter,

@@ -5,11 +5,14 @@ multiplications — a 5x5 mask times a 5x5 per-pixel neighbourhood — and ships
 them to Gemmini.  Its reported limitation is that 5x5 operands underfill the
 16x16 systolic array.
 
-This kernel is the TPU-native fix: im2col happens *inside* VMEM, batching a
-whole (bh, bw) pixel tile into a ``(bh, bw, kh*kw)`` patch tensor that is
-multiplied against **all masks at once** — ``(n_masks, kh*kw)`` — in a single
-MXU-friendly GEMM.  The patch tensor never touches HBM, and all three Canny
-masks (Gauss, Sobel-x, Sobel-y) share one im2col pass.
+This kernel is the TPU-native fix: every mask row becomes a banded
+(Toeplitz) matrix, so a ``(bh, 3*bw)`` strip of halo rows times one
+``(3*bw, n_masks*bw)`` band is that mask row applied to every pixel of the
+tile for **all masks at once** — ``kh`` aligned MXU GEMMs per tile, with the
+row offsets taken by a sublane rotate.  Nothing is re-laid out in VMEM (an
+im2col onto a new minor axis is a reshape the TPU compiler refuses), the
+patches never touch HBM, and all Canny masks of one pass (Gauss, or Sobel-x
+and Sobel-y, or the three fused 7x7 masks) share the strip.
 
 Streaming layout (the batched fast path):
   * the grid is ``(batch, row_block, col_block)`` — a leading batch axis so a
@@ -33,52 +36,62 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .tiles import acc_dtype as _acc_dtype
 from .tiles import round_up as _round_up
 
+# The TPU's MXU multiplies bf16 by default; HIGHEST makes it split each f32
+# operand into three bf16 parts, so integer-valued operands of up to 16
+# significant bits (uint8 pixels, the integer Gaussian sums that feed the
+# Sobel pass) multiply and accumulate exactly.  Mosaic honours it inside the
+# kernel (``tpu.contract_precision<fp32>``).
+PRECISION = jax.lax.Precision.HIGHEST
 
-def _conv_kernel(*refs, bh, bw, kh, kw, acc_dtype):
-    # refs: 9 halo-neighbour image blocks (row-major 3x3), masks, output.
-    nbr, masks_ref, o_ref = refs[:9], refs[9], refs[10]
-    ph, pw = kh // 2, kw // 2
-    blocks = [
-        [nbr[3 * r + c][...].reshape(bh, bw) for c in range(3)]
+
+def band_matrices(masks: jax.Array, bw: int) -> jax.Array:
+    """Banded (Toeplitz) form of ``masks`` for one column tile.
+
+    Returns ``T`` of shape ``(kh, 3*bw, n_masks*bw)`` with
+    ``T[dy, bw + j - pw + dx, m*bw + j] = masks[m, dy, dx]``: a row of the
+    three-tile-wide halo strip times ``T[dy]`` is mask row ``dy`` of every
+    mask correlated along the columns of the centre tile.  Pure gather and
+    select, so the entries are the mask values bit for bit.
+    """
+    n_masks, kh, kw = masks.shape
+    pw = kw // 2
+    c = np.arange(3 * bw)[:, None]
+    j = np.arange(bw)[None, :]
+    dx = c - bw - j + pw                      # (3bw, bw) tap column index
+    valid = (dx >= 0) & (dx < kw)
+    taps = jnp.asarray(masks, jnp.float32)[:, :, np.clip(dx, 0, kw - 1)]
+    band = jnp.where(jnp.asarray(valid), taps, 0.0)  # (M, kh, 3bw, bw)
+    return band.transpose(1, 2, 0, 3).reshape(kh, 3 * bw, n_masks * bw)
+
+
+def _conv_kernel(*refs, bh, bw, kh, n_masks):
+    # refs: 9 halo-neighbour image blocks (row-major 3x3), band, output.
+    nbr, band_ref, o_ref = refs[:9], refs[9], refs[10]
+    ph = kh // 2
+    rows = [
+        jnp.concatenate(
+            [nbr[3 * r + c][0].astype(jnp.float32) for c in range(3)],
+            axis=1,
+        )
         for r in range(3)
     ]
-
-    # Assemble only the (bh + 2*ph, bw + 2*pw) halo slab around the centre
-    # tile: ph/pw-wide strips of the neighbours, never the full 3x3 tile.
-    def strip(row, rs):
-        left, centre, right = row
-        parts = ([left[rs, bw - pw :]] if pw else []) + [centre[rs, :]] + (
-            [right[rs, : pw]] if pw else []
-        )
-        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 1)
-
-    pieces = ([strip(blocks[0], slice(bh - ph, bh))] if ph else []) + [
-        strip(blocks[1], slice(None))
-    ] + ([strip(blocks[2], slice(0, ph))] if ph else [])
-    slab = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, 0)
-    # On-chip im2col: static shifted windows stacked on a new minor axis.
-    patches = jnp.stack(
-        [
-            slab[dy : dy + bh, dx : dx + bw]
-            for dy in range(kh)
-            for dx in range(kw)
-        ],
-        axis=-1,
-    )  # (bh, bw, kh*kw)
-    masks = masks_ref[...]  # (n_masks, kh*kw)
-    # One GEMM for every mask: (M, K) x (bh, bw, K) -> (M, bh, bw).
-    out = jax.lax.dot_general(
-        masks.astype(acc_dtype),
-        patches.astype(acc_dtype),
-        dimension_numbers=(((1,), (2,)), ((), ())),
-        preferred_element_type=acc_dtype,
-    )
-    o_ref[...] = out[None].astype(o_ref.dtype)
+    strip = jnp.concatenate(rows, axis=0)          # (3bh, 3bw) halo strip
+    acc = jnp.zeros((bh, n_masks * bw), jnp.float32)
+    for dy in range(kh):
+        # Rows [bh - ph + dy, 2bh - ph + dy) of the strip, brought to the
+        # top by a sublane rotate so the slice stays tile-aligned.
+        shift = (2 * bh + ph - dy) % (3 * bh)
+        win = pltpu.roll(strip, shift, 0)[:bh] if shift else strip[:bh]
+        acc = acc + jnp.dot(win, band_ref[dy], precision=PRECISION,
+                            preferred_element_type=jnp.float32)
+    for m in range(n_masks):
+        o_ref[0, m] = acc[:, m * bw : (m + 1) * bw].astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -88,7 +101,7 @@ def conv2d_gemm(
     image: jax.Array,
     masks: jax.Array,
     *,
-    bh: int = 8,
+    bh: int = 16,
     bw: int = 128,
     out_dtype=None,
     interpret: bool = False,
@@ -100,9 +113,11 @@ def conv2d_gemm(
     a leading batch grid axis.
 
     ``bh``/``bw`` tile the rows/columns; non-multiple shapes are padded up
-    and cropped.  Accumulation follows ``tiles.acc_dtype``: int32 for
-    integer inputs (the paper's integer pipeline), f16 for f16 inputs (the
-    low-precision gradient tier), f32 otherwise.
+    and cropped (the TPU needs ``bh % 8 == 0`` and ``bw % 128 == 0``).  The
+    GEMM runs in f32 at HIGHEST precision whatever the input dtype, so
+    integer inputs (the paper's integer pipeline, the int8 tier) come back
+    exact as long as their sums stay below 2**24; the result is cast to
+    ``out_dtype`` (int32 for integer inputs, else the input dtype).
     """
     squeeze = image.ndim == 2
     if squeeze:
@@ -110,7 +125,6 @@ def conv2d_gemm(
     N, H, W = image.shape
     n_masks, kh, kw = masks.shape
     integer = jnp.issubdtype(image.dtype, jnp.integer)
-    acc_dtype = _acc_dtype(image.dtype)
     if out_dtype is None:
         out_dtype = jnp.int32 if integer else image.dtype
 
@@ -123,7 +137,7 @@ def conv2d_gemm(
     padded = jnp.pad(
         image, ((0, 0), (bh, Hb - H + bh), (bw, Wb - W + bw))
     )
-    flat_masks = masks.reshape(n_masks, kh * kw)
+    band = band_matrices(masks, bw)
 
     nbr_specs = [
         pl.BlockSpec(
@@ -134,17 +148,16 @@ def conv2d_gemm(
         for dj in range(3)
     ]
     out = pl.pallas_call(
-        functools.partial(
-            _conv_kernel, bh=bh, bw=bw, kh=kh, kw=kw, acc_dtype=acc_dtype
-        ),
+        functools.partial(_conv_kernel, bh=bh, bw=bw, kh=kh,
+                          n_masks=n_masks),
         grid=(N, Hb // bh, Wb // bw),
         in_specs=nbr_specs
-        + [pl.BlockSpec((n_masks, kh * kw), lambda n, i, j: (0, 0))],
+        + [pl.BlockSpec(band.shape, lambda n, i, j: (0, 0, 0))],
         out_specs=pl.BlockSpec(
             (1, n_masks, bh, bw), lambda n, i, j: (n, 0, i, j)
         ),
         out_shape=jax.ShapeDtypeStruct((N, n_masks, Hb, Wb), out_dtype),
         interpret=interpret,
-    )(*([padded] * 9), flat_masks)
+    )(*([padded] * 9), band)
     out = out[:, :, :H, :W]
     return out[0] if squeeze else out

@@ -7,16 +7,16 @@ cannot hide, so Gemmini gives it nothing (Table 7: 1.07x-1.16x).
 The TPU adaptation dissolves the dependency instead of tolerating it:
 
   1. ``rho[p, theta] = x_p * cos(theta) + y_p * sin(theta)`` for *all* edge
-     pixels and angles at once is a single ``(n_pix, C) @ (C, n_theta)`` GEMM
-     — MXU work (this is the paper's own conv->matmul move applied to the
-     stage the paper gave up on).
-  2. The vote histogram becomes a one-hot contraction: for a rho-bin block
+     pixels and angles at once — the ``(n_pix, C) @ (C, n_theta)`` product
+     with C = 3 homogeneous coordinates, evaluated as broadcast
+     multiply-adds on the VPU (C = 3 would fill 3 rows of the MXU).
+  2. The vote histogram becomes a masked reduction: for a rho-bin block
      ``[r0, r0+br)`` and a theta block ``[t0, t0+bt)``,
-     ``votes[r, t] = sum_p w_p * [rho_idx[p, t] == r]`` — a masked reduction
-     over pixels, accumulated in a VMEM-resident ``(br, bt)`` tile.  No
-     serialized read-modify-write anywhere.  Blocking theta keeps the peak
-     one-hot intermediate at ``(br, bp, bt)`` instead of the old
-     ``(br, bp, n_theta)`` broadcast.
+     ``votes[r, t] = sum_p w_p * [rho_idx[p, t] == r]``, one rho row at a
+     time, accumulated in a VMEM-resident ``(br, bt)`` tile.  No
+     serialized read-modify-write anywhere, and no ``(br, bp, bt)`` one-hot
+     intermediate (at 128 x 256 x 128 it alone would fill the TPU's 16 MB
+     of scoped VMEM).
 
 Grid: ``(batch, rho_blocks, theta_blocks, pixel_blocks)`` with pixels
 innermost so the vote tile stays output-stationary in scratch (same dataflow
@@ -87,15 +87,25 @@ def _vote_kernel(xy_ref, w_ref, trig_ref, o_ref, acc_ref, *, br):
     w = w_ref[...].reshape(bp, 1)        # (bp, 1) edge weights (0 => skip)
     trig = trig_ref[...]                 # (C, bt) cos/sin(/offset) columns
 
-    # Stage 1: the rho GEMM for this theta block.
-    rho = jnp.dot(xy, trig, preferred_element_type=jnp.float32)  # (bp, bt)
-    rho_idx = jnp.floor(rho).astype(jnp.int32)  # bin index (pre-offset)
+    # Stage 1: rho for this theta block.  K = C <= 3 would fill 3 of the
+    # MXU's 128 contraction rows, so it is C broadcast multiply-adds on the
+    # VPU, in f32, in column order.  The oracle's f32 dot on the CPU fuses
+    # the second product into a multiply-add, so where rho lies within an
+    # ulp of a bin edge the two can bin a vote one rho bin apart (about 4
+    # in 10**6 pixel-theta pairs over a 480x640 frame).
+    rho = xy[:, 0:1] * trig[0:1, :]
+    for c in range(1, C):
+        rho = rho + xy[:, c : c + 1] * trig[c : c + 1, :]      # (bp, bt)
+    rel = jnp.floor(rho).astype(jnp.int32) - pl.program_id(1) * br
 
-    # Stage 2: one-hot contraction against this rho block.
-    r0 = pl.program_id(1) * br
-    bins = r0 + jax.lax.broadcasted_iota(jnp.int32, (br, 1, 1), 0)
-    onehot = (rho_idx[None, :, :] == bins).astype(jnp.float32)  # (br, bp, bt)
-    acc_ref[...] += jnp.sum(onehot * w[None, :, :], axis=1)     # (br, bt)
+    # Stage 2: histogram of this pixel block into the (br, bt) rho block,
+    # one rho row at a time (a masked sublane reduction).
+    def row(r, carry):
+        hits = jnp.sum(jnp.where(rel == r, w, 0.0), axis=0, keepdims=True)
+        acc_ref[pl.ds(r, 1), :] += hits
+        return carry
+
+    jax.lax.fori_loop(0, br, row, 0)
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _flush():
@@ -113,7 +123,7 @@ def hough_vote(
     n_rho: int,
     br: int = 128,
     bp: int = 256,
-    bt: int = 64,
+    bt: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
     """Accumulate Hough votes.
@@ -130,7 +140,10 @@ def hough_vote(
       trig:    (C, n_theta) f32, rows ``cos(theta)`` / ``sin(theta)`` (and
                the offset row for C=3) already divided by the rho resolution.
       n_rho:   number of rho bins.
-      br/bp/bt: rho-bin / pixel / theta block sizes.
+      br/bp/bt: rho-bin / pixel / theta block sizes.  The theta axis is
+               padded to a multiple of ``bt``, so the TPU's 128-lane block
+               rule holds for any ``n_theta`` (a 40-bin gate, the 180-bin
+               sweep).
 
     Returns: (n_rho, n_theta) f32 vote accumulator (paper's
     ``accumulators``), with a leading N axis when ``weights`` is batched.
@@ -150,7 +163,6 @@ def hough_vote(
 
     bp = min(bp, _round_up(n_pix, 8))
     br = min(br, _round_up(n_rho, 8))
-    bt = min(bt, n_theta)
     P = _round_up(n_pix, bp)
     N_rho = _round_up(n_rho, br)
     N_theta = _round_up(n_theta, bt)

@@ -13,6 +13,8 @@ The default is chosen from the backend at call time and can be forced via
 ``repro.kernels.ops.set_default_impl(...)`` or ``REPRO_KERNEL_IMPL``.
 This mirrors the paper's heterogeneous dispatch: the same call site runs on
 the accelerator when one is attached and on the host pipeline otherwise.
+On a TPU backend ``interpret`` is refused: it would run the kernel bodies
+on the host while the run looks like a chip run.
 """
 
 from __future__ import annotations
@@ -26,7 +28,7 @@ import jax.numpy as jnp
 from . import ref
 from .conv2d_gemm import conv2d_gemm as _conv_pallas
 from .flash_attention import flash_attention as _attn_pallas
-from .fused_detect import fused_detect as _fused_pallas
+from .fused_detect import fused_weights as _fused_weights_pallas
 from .hough_vote import compact_edges as _compact_edges
 from .hough_vote import hough_vote as _hough_pallas
 from .ssd_scan import ssd_scan as _ssd_pallas
@@ -44,14 +46,19 @@ def set_default_impl(impl: Optional[str]) -> None:
 
 
 def resolve_impl(impl: Optional[str] = None) -> str:
-    if impl is not None:
-        return impl
-    if _default_impl is not None:
-        return _default_impl
-    env = os.environ.get("REPRO_KERNEL_IMPL")
-    if env:
-        return env
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    on_tpu = jax.default_backend() == "tpu"
+    chosen = impl or _default_impl or os.environ.get("REPRO_KERNEL_IMPL")
+    if chosen is None:
+        return "pallas" if on_tpu else "xla"
+    if chosen not in _VALID:
+        raise ValueError(f"impl must be one of {_VALID}, got {chosen!r}")
+    if chosen == "interpret" and on_tpu:
+        raise ValueError(
+            "impl='interpret' runs Pallas kernel bodies on the host; it is "
+            "refused on a TPU backend (use 'pallas', or name 'xla' for the "
+            "jnp reference)"
+        )
+    return chosen
 
 
 def tiled_matmul(x, y, *, out_dtype=None, impl=None, **kw):
@@ -86,74 +93,49 @@ def default_max_edges(n_pix: int) -> int:
     return max(256, n_pix // 16)
 
 
-def grad_hits(image, *, stride, thresh, corridors=None, widen=0.0,
-              impl=None):
+def grad_hits(image, *, stride, thresh, impl=None):
     """Downsampled-gradient hit count (the autotune estimator's reduction).
 
     Element-wise + reduction (VPU work): every impl routes to the jnp form
     in ``ref.py`` — a Pallas variant would buy nothing, but the dispatch
     seam keeps the estimator swappable like every other op here.
-    ``corridors``/``widen`` make the count corridor-aware for the fused
-    path's tier selector (see ``ref.grad_hits``).
     """
     del impl  # single implementation; signature matches the package
-    return ref.grad_hits(
-        image, stride=stride, thresh=thresh, corridors=corridors,
-        widen=widen,
-    )
+    return ref.grad_hits(image, stride=stride, thresh=thresh)
 
 
 def fused_weights(image, corridors=None, *, cfg, edge_threshold, impl=None):
-    """Thresholded, corridor-filtered flat edge weights (pre-compaction).
+    """Kernel A: thresholded, corridor-filtered flat edge weights.
 
-    The fused module's tier selector counts this intermediate *exactly*
-    before compaction (``core.hough.fused_hough_tiered`` on a host
-    backend) — the buffer size then matches the staged tiered dispatch
-    instead of over-provisioning from the pre-Canny estimate.  Pure
-    element-wise VPU work, so like ``grad_hits`` every impl routes to the
-    jnp form; on the TPU path the weights never leave kernel A's VMEM and
-    this seam is not used.
+    The whole Canny front end, the vote-weight threshold and the corridor
+    filter in one dispatch; returns ``(..., H*W)`` f32 in raster order.
+    The fused module counts these exactly to pick its compaction tier
+    (``core.hough.fused_hough_tiered``), then compacts
+    (``compact_raster``) and votes (kernel B).  The oracle is
+    ``ref.fused_weights``.
     """
-    del impl  # single implementation; signature matches the package
-    return ref.fused_weights(
-        image, cfg=cfg, edge_threshold=edge_threshold, corridors=corridors
+    impl = resolve_impl(impl)
+    if impl in ("xla", "stencil"):
+        return ref.fused_weights(
+            image, cfg=cfg, edge_threshold=edge_threshold,
+            corridors=corridors,
+        )
+    return _fused_weights_pallas(
+        image, corridors, cfg=cfg, edge_threshold=edge_threshold,
+        interpret=(impl == "interpret"),
     )
 
 
-def compact_raster(weights, *, width, max_edges, impl=None):
+def compact_raster(weights, *, width, max_edges):
     """Raster-layout compaction: scatter flat indices, rebuild (x, y, 1).
 
     ``compact_edges`` with the coordinate rows taken out of the scatter
     payload — valid whenever the caller owns the raster layout (the fused
     hot path).  Bit-identical output to ``compact_edges`` on the same
-    weights; see ``ref.compact_raster`` for the layout argument.
+    weights; see ``ref.compact_raster`` for the layout argument.  A prefix
+    sum and a scatter, so XLA runs it on every backend.
     """
-    del impl  # single implementation; signature matches the package
     return ref.compact_raster(weights, width=width, max_edges=max_edges)
-
-
-def fused_detect(image, corridors=None, *, cfg, edge_threshold, max_edges,
-                 impl=None):
-    """Fused canny -> corridor filter -> compact (hot-path kernel A).
-
-    One dispatch replaces the staged canny + compaction round trips: the
-    frame goes in, a compacted ``(max_edges, 3)`` homogeneous edge list
-    (plus weights) comes out, and nothing in between touches HBM.  Feed
-    the result to ``hough_vote(..., compact=False)`` (kernel B).  The
-    oracle is ``ref.fused_detect``; the contract is bit-exact with the
-    staged path when ``corridors`` is None / full coverage and the edge
-    count fits ``max_edges``.
-    """
-    impl = resolve_impl(impl)
-    if impl in ("xla", "stencil"):
-        return ref.fused_detect(
-            image, cfg=cfg, edge_threshold=edge_threshold,
-            max_edges=max_edges, corridors=corridors,
-        )
-    return _fused_pallas(
-        image, corridors, cfg=cfg, edge_threshold=edge_threshold,
-        max_edges=max_edges, interpret=(impl == "interpret"),
-    )
 
 
 def hough_vote(xy, weights, trig, *, n_rho, impl=None, compact=False,

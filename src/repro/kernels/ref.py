@@ -46,7 +46,9 @@ def conv2d_gemm(image: jax.Array, masks: jax.Array, *, out_dtype=None
         axis=-1,
     ).astype(acc)
     flat = masks.reshape(n_masks, kh * kw).astype(acc)
-    out = jnp.einsum("...hwk,mk->...mhw", patches, flat)
+    # HIGHEST: on a TPU the default would round f32 operands to bf16.
+    out = jnp.einsum("...hwk,mk->...mhw", patches, flat,
+                     precision=jax.lax.Precision.HIGHEST)
     return out.astype(out_dtype)
 
 
@@ -80,8 +82,7 @@ def conv2d_stencil(image: jax.Array, masks: jax.Array, *, out_dtype=None
     return jnp.stack(outs, axis=-3).astype(out_dtype)
 
 
-def grad_hits(image: jax.Array, *, stride: int, thresh: float,
-              corridors: jax.Array | None = None, widen: float = 0.0
+def grad_hits(image: jax.Array, *, stride: int, thresh: float
               ) -> jax.Array:
     """Downsampled finite-difference gradient hit count (per frame).
 
@@ -92,36 +93,13 @@ def grad_hits(image: jax.Array, *, stride: int, thresh: float,
     Returns an int32 count per leading-axis frame ((..., H, W) -> (...)).
     Element-wise + reduction — VPU work, no Pallas variant needed; it lives
     here so the estimator shares the kernel package's dispatch/oracle
-    structure and a future fused on-device tuner has one seam to replace.
-
-    When ``corridors`` (C, 4) rho windows are given (see ``corridor_keep``),
-    coarse hits outside every corridor are not counted — the fused path's
-    tier selector sizes its buffer for the *filtered* edge set.  ``widen``
-    inflates each window (in pixels) so a coarse cell whose fine pixels
-    straddle a corridor edge still counts; callers pass ~2*stride, the max
-    rho drift across a stride-wide cell plus slack, to keep the estimate an
-    upper bound.
+    structure.
     """
     img = jnp.asarray(image, jnp.float32)
     sub = img[..., ::stride, ::stride]
     gx = jnp.abs(sub[..., :, 1:] - sub[..., :, :-1])[..., :-1, :]
     gy = jnp.abs(sub[..., 1:, :] - sub[..., :-1, :])[..., :, :-1]
     hit = jnp.maximum(gx, gy) >= thresh
-    if corridors is not None:
-        Hs, Ws = hit.shape[-2:]
-        # Fine-pixel coordinates of each coarse cell's top-left corner.
-        yy = jnp.arange(Hs, dtype=jnp.float32)[:, None] * stride
-        xx = jnp.arange(Ws, dtype=jnp.float32)[None, :] * stride
-        cor = jnp.asarray(corridors, jnp.float32)
-        rho = (
-            xx[None] * cor[:, 0, None, None]
-            + yy[None] * cor[:, 1, None, None]
-        )  # (C, Hs, Ws)
-        keep = (
-            (rho >= (cor[:, 2, None, None] - widen))
-            & (rho <= (cor[:, 3, None, None] + widen))
-        ).any(axis=0)
-        hit = hit & keep
     return hit.sum(axis=(-2, -1), dtype=jnp.int32)
 
 
@@ -140,7 +118,11 @@ def hough_vote(xy: jax.Array, weights: jax.Array, trig: jax.Array,
         return jax.vmap(
             lambda w: hough_vote(xy, w, trig, n_rho=n_rho)
         )(weights)
-    rho = xy.astype(jnp.float32) @ trig.astype(jnp.float32)  # (P, n_theta)
+    # HIGHEST: a TPU's default pass rounds the operands to bf16, which
+    # moves pixel coordinates past 256 by whole bins; on the CPU this is
+    # the plain f32 dot.
+    rho = jnp.dot(xy.astype(jnp.float32), trig.astype(jnp.float32),
+                  precision=jax.lax.Precision.HIGHEST)  # (P, n_theta)
     idx = jnp.floor(rho).astype(jnp.int32)
     n_theta = trig.shape[1]
     votes = jnp.zeros((n_rho, n_theta), jnp.float32)
@@ -200,6 +182,20 @@ def hough_vote_gated(xy: jax.Array, weights: jax.Array, trig: jax.Array,
     return jnp.where(mask, full, jnp.zeros_like(full))
 
 
+def snap_corridors(corridors: jax.Array) -> jax.Array:
+    """Corridor rows with ``cos``/``sin`` snapped to multiples of 2**-13.
+
+    With integer pixel coordinates below 2**11 every product of the rho test
+    and their sum are then exact f32, so the test gives the same answer on
+    every backend whether or not it fuses the multiply-add (XLA on the CPU
+    does).  A corridor edge moves by at most ``(W + H) * 2**-14`` px (0.07
+    px at 480x640).
+    """
+    cor = jnp.asarray(corridors, jnp.float32)
+    normal = jnp.floor(cor[:, :2] * 8192.0 + 0.5) * (1.0 / 8192.0)
+    return jnp.concatenate([normal, cor[:, 2:]], axis=1)
+
+
 def corridor_keep(xy: jax.Array, corridors: jax.Array) -> jax.Array:
     """Which pixels fall inside at least one rho corridor.
 
@@ -210,13 +206,14 @@ def corridor_keep(xy: jax.Array, corridors: jax.Array) -> jax.Array:
     survives if its rho along any corridor's normal lands in that
     corridor's window; padding rows just repeat a real corridor (the OR is
     idempotent).  ``hough.full_corridors`` builds windows that pass
-    everything.
+    everything.  The normals are snapped first (``snap_corridors``).
 
     ``xy`` is (..., P, C>=2) with columns (x, y, ...); returns (..., P) bool.
     """
     xyf = xy[..., :2].astype(jnp.float32)
-    cor = jnp.asarray(corridors, jnp.float32)
-    rho = xyf @ cor[:, :2].T  # (..., P, C)
+    cor = snap_corridors(corridors)
+    # Elementwise, like the fused kernel (a TPU runs a K=2 dot in bf16).
+    rho = xyf[..., 0:1] * cor[:, 0] + xyf[..., 1:2] * cor[:, 1]  # (..., P, C)
     return ((rho >= cor[:, 2]) & (rho <= cor[:, 3])).any(axis=-1)
 
 
@@ -229,7 +226,8 @@ def fused_weights(image: jax.Array, *, cfg, edge_threshold: float,
     threshold exactly as the staged ``hough`` stage does, and zeroes the
     weights of pixels outside every corridor.  Returns ``(..., H*W)`` f32 —
     the intermediate the fused module's exact tier selector counts before
-    compaction (``core.hough.fused_hough_tiered`` on the xla path).
+    compaction (``core.hough.fused_hough_tiered``); the oracle of kernel A
+    (``kernels.fused_detect.fused_weights``).
     """
     import dataclasses
 
@@ -254,9 +252,8 @@ def compact_raster(weights: jax.Array, *, width: int, max_edges: int):
     fused path owns the raster layout, so the pixel coordinate is a pure
     function of the flat index — compaction only needs to scatter one
     int32 per surviving pixel and reconstruct ``(idx % W, idx // W, 1)``
-    from the ``(max_edges,)`` result afterwards.  On a host backend this
-    cuts the scatter payload 4x (the dominant compaction cost); on the
-    TPU kernel it is the natural VMEM form (kernel A emits an index list).
+    from the ``(max_edges,)`` result afterwards, a quarter of the scatter
+    payload.  The fused path runs it after kernel A on every backend.
 
     Same contract as ``compact_edges``: raster order, rows past the edge
     count zeroed, edges beyond ``max_edges`` dropped — and bit-identical
@@ -285,29 +282,6 @@ def compact_raster(weights: jax.Array, *, width: int, max_edges: int):
         axis=1,
     )
     return jnp.where(slot[:, None], cxy, 0.0), cw
-
-
-def fused_detect(image: jax.Array, *, cfg, edge_threshold: float,
-                 max_edges: int, corridors: jax.Array | None = None):
-    """Fused-hot-path oracle: gradient -> threshold -> corridor filter ->
-    compact, in one jnp function.
-
-    Semantics of record for ``kernels.fused_detect`` (the Pallas kernel A):
-    ``fused_weights`` produces the thresholded, corridor-filtered weights
-    and ``compact_raster`` compacts the survivors in raster order into a
-    static ``(max_edges, 3)`` homogeneous ``(x, y, 1)`` buffer (first
-    ``max_edges`` kept, trailing edges dropped — the same overflow contract
-    as ``compact_edges``).  Kernel B is the existing vote kernel, fed this
-    buffer.
-
-    Returns ``(cxy, cw)`` of shape ``(..., max_edges, 3)`` /
-    ``(..., max_edges)`` in f32.
-    """
-    W = image.shape[-1]
-    w = fused_weights(
-        image, cfg=cfg, edge_threshold=edge_threshold, corridors=corridors
-    )
-    return compact_raster(w, width=W, max_edges=max_edges)
 
 
 def attention(q, k, v, *, causal=True, window=None, q_offset=0):

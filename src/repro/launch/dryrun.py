@@ -1,15 +1,8 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# (the two lines above must stay first: jax locks device count on first init)
-if os.environ.get("REPRO_EXTRA_XLA_FLAGS"):
-    os.environ["XLA_FLAGS"] += " " + os.environ["REPRO_EXTRA_XLA_FLAGS"]
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST stay the first statements in this file — jax locks
-the device count on first initialization, and the production meshes need
-512 placeholder host devices.  Everything else (smoke tests, benches) runs
-in separate processes that see 1 device.
+``main`` pins this process to the CPU backend with 512 placeholder host
+devices (the production meshes) before JAX initializes a backend; it never
+touches an accelerator.  Importing the module changes nothing.
 
 Per cell this produces, with zero array allocation:
   * ``compiled.memory_analysis()``  — proof the cell fits per-device HBM,
@@ -20,14 +13,13 @@ Per cell this produces, with zero array allocation:
 
 Artifacts are JSON files under ``experiments/dryrun/`` consumed by
 ``launch/roofline.py`` and the ``benchmarks`` tables (ROADMAP.md tracks
-the open sweep items).  Already-complete cells are
-skipped (incremental reruns), and each cell can run in a fresh subprocess
-(``--subprocess``) so one cell's compile-memory spike cannot kill the whole
-sweep.
+the open sweep items).  Already-complete cells are skipped (incremental
+reruns).
 """
 
 import argparse
 import json
+import os
 import re
 import sys
 import time
@@ -314,9 +306,12 @@ def main(argv=None):
     ap.add_argument("--out", default="experiments/dryrun")
     ap.add_argument("--force", action="store_true",
                     help="recompute cells that already have artifacts")
-    ap.add_argument("--subprocess", action="store_true",
-                    help="run each cell in a fresh python process")
     args = ap.parse_args(argv)
+    flags = "--xla_force_host_platform_device_count=512"
+    if os.environ.get("REPRO_EXTRA_XLA_FLAGS"):
+        flags += " " + os.environ["REPRO_EXTRA_XLA_FLAGS"]
+    os.environ["XLA_FLAGS"] = flags   # read when the backend initializes
+    jax.config.update("jax_platforms", "cpu")
 
     archs = [args.arch] if args.arch else None
     shapes = [args.shape] if args.shape else None
@@ -329,17 +324,6 @@ def main(argv=None):
         out_path = os.path.join(args.out, cell_id + ".json")
         if os.path.exists(out_path) and not args.force:
             print(f"[skip] {cell_id} (artifact exists)", flush=True)
-            continue
-        if args.subprocess:
-            import subprocess
-            cmd = [
-                sys.executable, "-m", "repro.launch.dryrun",
-                "--arch", arch, "--shape", shape_name, "--mesh", mesh_name,
-                "--out", args.out,
-            ] + (["--force"] if args.force else [])
-            r = subprocess.run(cmd)
-            if r.returncode != 0:
-                failures.append(cell_id)
             continue
         try:
             run_cell(arch, shape_name, multi_pod=multi_pod, out_dir=args.out)

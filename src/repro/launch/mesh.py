@@ -16,12 +16,20 @@ gradient reduction (optionally int8-compressed, train/compression.py).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the partitioner places what the
+    logical-axis constraints (``sharding.constrain``) leave open.  JAX's
+    default is Explicit axes, which refuse those constraints."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_replica_mesh(n: int | None = None):
@@ -36,7 +44,7 @@ def make_replica_mesh(n: int | None = None):
     """
     devs = jax.devices()
     n = min(n or len(devs), len(devs))
-    return jax.make_mesh((n,), ("replica",))
+    return _auto_mesh((n,), ("replica",))
 
 
 def replica_devices(n: int) -> list:
@@ -62,6 +70,6 @@ def make_host_mesh(*, multi_pod: bool = False, n: int | None = None):
         d = max(
             s for s in range(1, int(rest ** 0.5) + 1) if rest % s == 0
         )
-        return jax.make_mesh((2, rest // d, d), ("pod", "data", "model"))
+        return _auto_mesh((2, rest // d, d), ("pod", "data", "model"))
     d = max(s for s in range(1, int(n ** 0.5) + 1) if n % s == 0)
-    return jax.make_mesh((n // d, d), ("data", "model"))
+    return _auto_mesh((n // d, d), ("data", "model"))

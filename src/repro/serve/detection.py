@@ -141,6 +141,7 @@ from repro.core.control import (
     ControlConfig, LateralController, SteeringCommand,
 )
 from repro.core.geometry import CameraConfig, CameraGeometry
+from repro.core.hough import full_corridors
 from repro.core.tracking import (
     LaneTracker, Track, TrackerConfig, tracks_as_peaks,
 )
@@ -1683,7 +1684,13 @@ class DetectionService:
             grid.slots = [None] * self.batch_size
             grid.staged = np.zeros_like(grid.staged)
             return True
+        # every operand ships explicitly, so a warm dispatch transfers
+        # nothing implicitly under the guard below
         imgs = self.plans.put(grid.staged)
+        if theta_bins is not None:
+            theta_bins = self.plans.put(theta_bins)
+        if corridors is not None:
+            corridors = self.plans.put(corridors)
         warm_key = (grid.shape, plan.cfg.render_output,
                     plan.cfg.hough.theta_band, plan.cfg.fused)
         was_warm = warm_key in self._warmed
@@ -1720,6 +1727,33 @@ class DetectionService:
         self.dispatch_log.append((grid.shape, grid.active, want_render))
         grid.slots = [None] * self.batch_size   # slots free immediately
         return True
+
+    def warm_up(self) -> None:
+        """Compile every plan binding a dispatch can take, before traffic:
+        per bucket the full sweep and, with ``gate_band``, the gated plan
+        and, with ``fused_corridors``, its fused twin (render bindings
+        compile on first use).  Each runs once on zero frames with an
+        all-pass gate and corridors; later dispatches of these bindings
+        run warm, under the transfer guard."""
+        n_theta = self.cfg.hough.n_theta
+        for shape, grid in self.grids.items():
+            imgs = self.plans.put(
+                np.zeros((self.batch_size,) + shape, np.float32))
+            variants = [(grid.plan, None, None)]
+            if self.gate_band is not None:
+                gated = grid.plan.with_theta_band(self.gate_band)
+                bins = self.plans.put(
+                    np.arange(self.gate_band, dtype=np.int32) % n_theta)
+                variants.append((gated, bins, None))
+                if self.fused_corridors is not None:
+                    variants.append((
+                        gated.with_fused(self.fused_corridors), bins,
+                        self.plans.put(full_corridors(self.fused_corridors)),
+                    ))
+            for plan, bins, cors in variants:
+                jax.block_until_ready(plan.run(imgs, bins, cors))
+                self._warmed.add((shape, False, plan.cfg.hough.theta_band,
+                                  plan.cfg.fused))
 
     def run(self, max_steps: int = 10_000) -> None:
         """Drive until the queues, slots, and in-flight batches drain

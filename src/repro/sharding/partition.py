@@ -73,8 +73,8 @@ DEFAULT_RULES: AxisRules = {
     # Full-sequence attention activations (B, H, L, hd): heads carry TP
     # when they divide; otherwise the *sequence* does (context-parallel
     # attention — GSPMD all-gathers K/V per shard instead of psumming
-    # (B, H, L, L) score tensors, the whisper/qwen 20/40-head fix visible
-    # in the benchmarks/t5_dp_scaling tables).  Dim order (batch, heads,
+    # (B, H, L, L) score tensors, the whisper/qwen 20/40-head fix).  Dim
+    # order (batch, heads,
     # attn_seq, head_dim) encodes the fallback.
     "attn_seq": ("model", None),
 }
@@ -214,23 +214,14 @@ _MANUAL_AXES: contextvars.ContextVar = contextvars.ContextVar(
 
 
 def _manual_axes_here() -> set:
-    """Mesh axes that are Manual in the current trace (inside shard_map).
-
-    Two sources: the abstract-mesh axis types (newer jax), plus the set our
-    ``shard_map`` wrapper records while tracing its body (works on jax
-    versions whose traces don't expose manual-ness).
-    """
-    manual = set(_MANUAL_AXES.get())
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and am.axis_names:
-            manual |= {
-                n for n, t in zip(am.axis_names, am.axis_types)
-                if "Manual" in str(t)
-            }
-    except Exception:
-        pass
-    return manual
+    """Mesh axes that are Manual in the current trace (inside shard_map):
+    the abstract mesh's Manual axes plus the set our ``shard_map`` wrapper
+    records while tracing its body."""
+    am = jax.sharding.get_abstract_mesh()
+    return set(_MANUAL_AXES.get()) | {
+        n for n, t in zip(am.axis_names, am.axis_types)
+        if t == jax.sharding.AxisType.Manual
+    }
 
 
 def constrain(
@@ -251,12 +242,6 @@ def constrain(
     mesh, active_rules = active
     spec = logical_to_spec(axes, x.shape, mesh, rules or active_rules)
     manual = _manual_axes_here()
-    if manual and not hasattr(jax, "shard_map"):
-        # Old-jax partial-manual shard_map: XLA's SPMD partitioner cannot
-        # honour auto-axis constraints inside a manual subgroup (it hard-
-        # crashes on IsManualSubgroup).  Constraints are hints, not
-        # semantics — drop them there and let GSPMD place the body freely.
-        return x
     if manual:
         def strip(entry):
             if entry is None:
@@ -272,13 +257,10 @@ def constrain(
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
               check_vma: bool = False):
-    """``jax.shard_map`` across jax versions.
+    """``jax.shard_map`` that records its manual axes for ``constrain``.
 
-    Newer jax exposes ``jax.shard_map(..., axis_names=..., check_vma=...)``;
-    older releases have ``jax.experimental.shard_map.shard_map`` where the
-    manual axis set is expressed as its complement (``auto``) and the
-    replication check is ``check_rep``.  ``axis_names`` is the set of
-    *manual* axes; ``None`` (the jax default) means all mesh axes.
+    ``axis_names`` is the set of *manual* axes; ``None`` (the jax default)
+    means all mesh axes.
     """
     manual = (
         frozenset(mesh.axis_names) if axis_names is None
@@ -296,17 +278,10 @@ def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
         finally:
             _MANUAL_AXES.reset(token)
 
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return jax.shard_map(
-            traced, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma, **kw,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset(mesh.axis_names) - manual
-    return _shard_map(
+    kw = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(
         traced, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma, auto=auto,
+        check_vma=check_vma, **kw,
     )
 
 

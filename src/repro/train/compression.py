@@ -63,23 +63,13 @@ def compressed_allreduce(x: jax.Array, err: jax.Array, axis_name: str):
     y = x.astype(jnp.float32) + err
     q, scale = _quantize(y)
     deq_own = q.astype(jnp.float32) * scale
-    if hasattr(jax, "shard_map"):
-        # int8 payload + f32 scale over the slow link
-        qs = jax.lax.all_gather(q, axis_name)      # (P, ...)
-        ss = jax.lax.all_gather(scale, axis_name)  # (P,)
-        n = qs.shape[0]
-        total = jnp.tensordot(
-            ss, qs.astype(jnp.float32).reshape(n, -1), axes=1
-        ).reshape(x.shape)
-    else:
-        # Old-jax partial-auto shard_map: every collective except psum
-        # trips the SPMD partitioner's IsManualSubgroup checks, so reduce
-        # the dequantized contributions directly.  Numerically the same sum
-        # of per-pod dequant(q, s) terms — the error-feedback semantics the
-        # tests pin down — but the int8 wire format only exists on jax
-        # versions whose partitioner can gather it.
-        n = jax.lax.psum(1, axis_name)
-        total = jax.lax.psum(deq_own, axis_name)
+    # int8 payload + f32 scale over the slow link
+    qs = jax.lax.all_gather(q, axis_name)      # (P, ...)
+    ss = jax.lax.all_gather(scale, axis_name)  # (P,)
+    n = qs.shape[0]
+    total = jnp.tensordot(
+        ss, qs.astype(jnp.float32).reshape(n, -1), axes=1
+    ).reshape(x.shape)
     return total / n, y - deq_own
 
 

@@ -89,6 +89,22 @@ def test_hough_gemm_equals_paper_loop(scene):
     np.testing.assert_allclose(np.asarray(fast), np.asarray(slow), atol=1e-3)
 
 
+def test_sum_squares_same_bits_fused_or_not(rng):
+    """The Canny magnitude's ``gx*gx + gy*gy`` gives the same f32 bits
+    whether or not the compiler fuses multiply-adds (jit on the CPU does,
+    op-by-op eager dispatch cannot), within two roundings of the exact
+    integer sum, for integer gradients up to the f32 tier's 2**18."""
+    from repro.core.canny import _sum_squares
+
+    gx, gy = rng.integers(-2**18 + 1, 2**18, (2, 200_000)).astype(np.float32)
+    fused = np.asarray(jax.jit(_sum_squares)(gx, gy))
+    unfused = np.asarray(_sum_squares(jnp.asarray(gx), jnp.asarray(gy)))
+    np.testing.assert_array_equal(fused, unfused)
+    exact = gx.astype(np.int64) ** 2 + gy.astype(np.int64) ** 2
+    err = np.abs(fused.astype(np.float64) - exact)
+    assert (err <= exact * 2.0**-23).all()
+
+
 def test_vote_conservation(scene):
     """Every edge pixel casts exactly n_theta votes (minus out-of-range)."""
     edges = canny(jnp.asarray(scene.image, jnp.float32), CannyConfig())

@@ -101,12 +101,6 @@ def test_elastic_checkpoint_resharding():
     """)
 
 
-@pytest.mark.skipif(
-    not hasattr(__import__("jax"), "shard_map"),
-    reason="partial-auto shard_map over a scanned model body aborts this "
-           "XLA's SPMD partitioner (IsManualSubgroup check, uncatchable); "
-           "needs the jax.shard_map era — see ROADMAP open items",
-)
 def test_pod_compressed_train_step():
     """int8 pod-compressed step runs on a (2,2,2) mesh and tracks the
     uncompressed step closely (error feedback)."""
@@ -127,7 +121,8 @@ def test_pod_compressed_train_step():
         batch = materialize_inputs(rng, cfg, ShapeSpec("t", 16, 8, "train"))
         opt = AdamWConfig(peak_lr=1e-3, warmup_steps=0, decay_steps=100)
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         with sharding.activate(mesh):
             comp = jax.jit(make_train_step_pod_compressed(m, opt, mesh))
             ref = jax.jit(make_train_step(m, opt))

@@ -48,14 +48,23 @@ def _frame(h: int, w: int, seed: int = 0) -> np.ndarray:
 
 
 def _ground_estimate(svc, clock, shape, dt, uid0=900):
-    """Measure the bucket's EMA at ``dt`` via warm no-deadline traffic."""
+    """Measure the bucket's EMA at ``dt`` via warm no-deadline traffic.
+
+    The ``is_ready`` poll is held off meanwhile: a batch the device has
+    already finished would be retired by it, and such a sample may not
+    raise the estimate, so whether ``dt`` above the default estimate
+    registers would depend on how fast the host ran the batch."""
+    reap, svc._reap = svc._reap, lambda: None
     warms = [DetectionRequest(uid=uid0 + u, frame=_frame(*shape, seed=u))
              for u in range(3)]
-    for w in warms:
-        svc.submit(w)
-        svc.step()
-        clock.advance(dt)
-    svc.drain()
+    try:
+        for w in warms:
+            svc.submit(w)
+            svc.step()
+            clock.advance(dt)
+        svc.drain()
+    finally:
+        svc._reap = reap
     assert all(w.ok for w in warms)
     assert svc.grids[shape].est_measured
 

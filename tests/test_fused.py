@@ -2,8 +2,9 @@
 
 Layers under test, bottom-up:
 
-  * kernel parity — ``ops.fused_detect`` (xla oracle, interpret Pallas
-    body) against the staged ``compact_edges`` construction, bit-for-bit;
+  * kernel parity — kernel A (``ops.fused_weights``: xla oracle,
+    interpret Pallas body) then ``ops.compact_raster``, against the staged
+    ``compact_edges`` construction, bit-for-bit;
   * ``compact_raster`` — the index-scatter compaction against the generic
     row-scatter ``compact_edges`` on the same weights;
   * corridor filtering — ``corridor_keep`` geometry, the filtered vote,
@@ -67,14 +68,20 @@ def _staged_compact(img, max_edges, corridors=None):
     return compact_edges(xy, w, max_edges=max_edges)
 
 
+def _fused_detect(img, corridors, max_edges, impl):
+    """The fused path's front: kernel A's weights, raster-compacted."""
+    w = ops.fused_weights(img, corridors, cfg=CANNY, edge_threshold=250.0,
+                          impl=impl)
+    return ops.compact_raster(w, width=img.shape[-1], max_edges=max_edges)
+
+
 # --- kernel parity ----------------------------------------------------------
 
 
 def test_fused_detect_matches_staged_compaction():
     for seed in range(4):
         img = _img(seed=seed)
-        got = ops.fused_detect(img, None, cfg=CANNY, edge_threshold=250.0,
-                               max_edges=256, impl="xla")
+        got = _fused_detect(img, None, 256, "xla")
         want = _staged_compact(img, 256)
         np.testing.assert_array_equal(np.asarray(got[0]),
                                       np.asarray(want[0]))
@@ -85,8 +92,7 @@ def test_fused_detect_matches_staged_compaction():
 def test_fused_detect_batched_and_overflow():
     imgs = jnp.stack([_img(seed=s) for s in range(3)])
     for max_edges in (16, 256):  # 16 overflows: same trailing-edge drop
-        got = ops.fused_detect(imgs, None, cfg=CANNY, edge_threshold=250.0,
-                               max_edges=max_edges, impl="xla")
+        got = _fused_detect(imgs, None, max_edges, "xla")
         want = _staged_compact(imgs, max_edges)
         np.testing.assert_array_equal(np.asarray(got[0]),
                                       np.asarray(want[0]))
@@ -98,12 +104,8 @@ def test_fused_detect_interpret_matches_oracle():
     img = _img(96, 128, seed=2)
     cors = jnp.asarray(np.array([[1.0, 0.0, 30.0, 100.0]], np.float32))
     for corridors in (None, cors):
-        a = ops.fused_detect(img, corridors, cfg=CANNY,
-                             edge_threshold=250.0, max_edges=128,
-                             impl="interpret")
-        b = ops.fused_detect(img, corridors, cfg=CANNY,
-                             edge_threshold=250.0, max_edges=128,
-                             impl="xla")
+        a = _fused_detect(img, corridors, 128, "interpret")
+        b = _fused_detect(img, corridors, 128, "xla")
         np.testing.assert_array_equal(np.asarray(a[0]), np.asarray(b[0]))
         np.testing.assert_array_equal(np.asarray(a[1]), np.asarray(b[1]))
 
@@ -166,6 +168,26 @@ def test_full_corridors_pass_everything():
     assert (cors[:, 3] == CORRIDOR_INF).all()
     xy = jnp.asarray(np.array([[0.0, 0.0], [1000.0, 1000.0]], np.float32))
     assert np.asarray(ref.corridor_keep(xy, jnp.asarray(cors))).all()
+
+
+def test_corridor_keep_exact_at_window_edges(rng):
+    """With snapped normals the rho test is exact: it matches a float64
+    evaluation at every 480x640 pixel, windows whose edges sit exactly on
+    some pixel's rho included (the closed window keeps that pixel)."""
+    H, W = 480, 640
+    jj, ii = np.meshgrid(np.arange(W), np.arange(H))
+    xy = np.stack([jj.ravel(), ii.ravel()], axis=1).astype(np.float32)
+    theta = rng.uniform(0.0, math.pi, 4)
+    cor = np.zeros((4, 4), np.float32)
+    cor[:, 0], cor[:, 1] = np.cos(theta), np.sin(theta)
+    normals = np.asarray(ref.snap_corridors(jnp.asarray(cor)))[:, :2]
+    rho = xy.astype(np.float64) @ normals.astype(np.float64).T  # (P, 4)
+    ends = rho[rng.integers(0, H * W, (2, 4)), np.arange(4)]
+    cor[:, 2], cor[:, 3] = ends.min(axis=0), ends.max(axis=0)
+    want = ((rho >= cor[:, 2]) & (rho <= cor[:, 3])).any(axis=1)
+    got = np.asarray(ref.corridor_keep(jnp.asarray(xy), jnp.asarray(cor)))
+    np.testing.assert_array_equal(got, want)
+    assert want.any() and not want.all()
 
 
 def test_corridor_filter_drops_off_corridor_votes():
@@ -386,6 +408,76 @@ def test_service_fused_engages_and_matches():
                                       np.asarray(r.result.peaks))
         np.testing.assert_array_equal(np.asarray(g.result.valid),
                                       np.asarray(r.result.valid))
+
+
+def test_service_ships_gate_and_corridors_before_guarded_dispatch(
+        monkeypatch):
+    """Warm gated/fused dispatches run under transfer_guard("disallow").
+    On the CPU backend an implicit numpy->device copy is not guarded, so
+    pin the contract directly: every gate and corridor array reaching
+    ``DetectionPlan.run`` is already a jax.Array on the service's device
+    (a TPU backend raises on the implicit copy)."""
+    import jax
+
+    from repro.core.plan import DetectionPlan
+    from repro.serve.detection import (
+        DetectionRequest, DetectionService, VirtualClock,
+    )
+
+    seen = []
+    real_run = DetectionPlan.run
+
+    def spy(self, images, theta_bins=None, corridors=None):
+        seen.append((theta_bins, corridors))
+        return real_run(self, images, theta_bins, corridors)
+
+    monkeypatch.setattr(DetectionPlan, "run", spy)
+    svc = DetectionService(
+        PipelineConfig(hough=HoughConfig(compact=True, max_edges="auto")),
+        buckets=((120, 160),), batch_size=1, prefetch=False,
+        clock=VirtualClock(), gate_band=40, fused_corridors=8,
+    )
+    device = jax.devices()[0]
+    for fr in make_drive_cycle("straight", 10, 120, 160, seed=0).frames:
+        svc.submit(DetectionRequest(uid=fr.t, frame=fr.scene.image,
+                                    session_id="ego"))
+        svc.run()
+        svc.clock.advance(0.01)
+    assert svc.gated_dispatches > 0 and svc.fused_dispatches > 0
+    svc.close()
+    operands = [a for pair in seen for a in pair if a is not None]
+    assert operands
+    for a in operands:
+        assert isinstance(a, jax.Array), type(a)
+        assert a.devices() == {device}
+
+
+def test_service_warm_up_covers_every_dispatch():
+    """After ``warm_up`` every binding a tracked session takes (full sweep,
+    gated, fused) is already warm: no dispatch compiles, and the gated and
+    fused ones all run under the transfer guard."""
+    from repro.serve.detection import (
+        DetectionRequest, DetectionService, VirtualClock,
+    )
+
+    svc = DetectionService(
+        PipelineConfig(hough=HoughConfig(compact=True, max_edges="auto")),
+        buckets=((120, 160),), batch_size=1, prefetch=False,
+        clock=VirtualClock(), gate_band=40, fused_corridors=4,
+    )
+    svc.warm_up()
+    warmed = set(svc._warmed)
+    assert {k[2:] for k in warmed} == {(None, False), (40, False), (40, True)}
+    for fr in make_drive_cycle("straight", 10, 120, 160, seed=0).frames:
+        req = DetectionRequest(uid=fr.t, frame=fr.scene.image,
+                               session_id="ego")
+        svc.submit(req)
+        svc.run()
+        assert req.ok
+        svc.clock.advance(0.01)
+    assert svc.gated_dispatches > 0 and svc.fused_dispatches > 0
+    assert svc._warmed == warmed
+    svc.close()
 
 
 # --- quantized gradient tiers ----------------------------------------------

@@ -11,12 +11,8 @@ from repro.sharding import (
 
 def _abstract_mesh(shape, names):
     # shape-only stand-in mesh: rules only read axis names and sizes.
-    # Newer jax takes (shape, names); jax<=0.4.x takes ((name, size), ...).
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(shape, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, shape)))
+    return AbstractMesh(shape, names)
 
 
 @pytest.fixture(scope="module")
