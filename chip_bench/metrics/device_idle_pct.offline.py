@@ -1,0 +1,3 @@
+"""Device: share of the traced window in which no op ran on the chip, %."""
+
+from chip_bench.layers import device_idle_pct as read  # noqa: F401

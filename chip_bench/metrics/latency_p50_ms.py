@@ -1,0 +1,5 @@
+"""End to end: median latency of the frames due in the window, from the due time to the terminal answer, ms."""
+
+from chip_bench.latency import percentile_ms
+
+read = percentile_ms(50)
