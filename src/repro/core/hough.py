@@ -146,10 +146,26 @@ def auto_max_edges(n_edges: int, height: int, width: int, *,
     the hand-tuned one, and past the cap both drop exactly the same
     trailing edges.
     """
-    for t in max_edge_tiers(height, width, base=base):
+    return tier_for(n_edges, max_edge_tiers(height, width, base=base))
+
+
+def tier_for(n_edges: int, tiers: tuple[int, ...]) -> int:
+    """The smallest tier that holds ``n_edges``, else the cap: the host
+    twin of the device's tier choice (``tier_index``)."""
+    for t in tiers:
         if int(n_edges) <= t:
             return t
-    return max_edge_tiers(height, width, base=base)[-1]
+    return tiers[-1]
+
+
+def tier_index(counts: jax.Array, tiers: tuple[int, ...]) -> jax.Array:
+    """Index into ``tiers`` of the tier that holds a batch's densest
+    frame (capped at the last): the one rule both tiered paths use."""
+    worst = counts.max().astype(jnp.int32)
+    return jnp.minimum(
+        sum((worst > t).astype(jnp.int32) for t in tiers),
+        len(tiers) - 1,
+    )
 
 
 def resolved_auto_config(cfg: HoughConfig, n_edges: int, height: int,
@@ -228,26 +244,35 @@ def hough_transform_tiered(edges: jax.Array, cfg: HoughConfig,
     stays finite: one compiled program per (shape, cfg), holding
     ``len(tiers)`` vote variants.
     """
+    return hough_transform_counted(edges, cfg, tiers, theta_bins,
+                                   scatter=scatter)[0]
+
+
+def hough_transform_counted(edges: jax.Array, cfg: HoughConfig,
+                            tiers: tuple[int, ...] | None = None,
+                            theta_bins: jax.Array | None = None, *,
+                            scatter: bool = True
+                            ) -> tuple[jax.Array, jax.Array | None]:
+    """``hough_transform_tiered`` that also returns the per-frame edge
+    counts its tier choice read, ``(votes, counts)`` (counts None on the
+    dense path, which chooses no tier)."""
     if not cfg.compact:
         return _hough_transform(
             edges, dataclasses.replace(cfg, max_edges=None), theta_bins,
             scatter=scatter,
-        )
+        ), None
     H, W = edges.shape[-2:]
     if tiers is None:
         tiers = max_edge_tiers(H, W)
-    counts = (edges >= cfg.edge_threshold).sum(axis=(-2, -1))
-    worst = counts.max().astype(jnp.int32)
-    idx = jnp.minimum(
-        sum((worst > t).astype(jnp.int32) for t in tiers),
-        len(tiers) - 1,
-    )
+    with jax.named_scope("compact"):
+        counts = (edges >= cfg.edge_threshold).sum(axis=(-2, -1))
+        idx = tier_index(counts, tiers)
     cfgs = [dataclasses.replace(cfg, max_edges=int(t)) for t in tiers]
     if theta_bins is None:
         branches = [
             functools.partial(_hough_transform, cfg=c) for c in cfgs
         ]
-        return jax.lax.switch(idx, branches, edges)
+        return jax.lax.switch(idx, branches, edges), counts
     branches = [
         functools.partial(
             lambda e, tb, cfg: _hough_transform(e, cfg, tb,
@@ -256,7 +281,7 @@ def hough_transform_tiered(edges: jax.Array, cfg: HoughConfig,
         )
         for c in cfgs
     ]
-    return jax.lax.switch(idx, branches, edges, theta_bins)
+    return jax.lax.switch(idx, branches, edges, theta_bins), counts
 
 
 @functools.partial(
@@ -281,12 +306,13 @@ def _hough_transform(edges: jax.Array, cfg: HoughConfig = HoughConfig(),
     n_rho = rho_bins(H, W, cfg)
     trig = hough_trig(H, W, cfg)
 
-    jj, ii = jnp.meshgrid(jnp.arange(W), jnp.arange(H))
-    xy = jnp.stack(
-        [jj.ravel(), ii.ravel(), jnp.ones(H * W, jnp.int32)], axis=1
-    ).astype(jnp.float32)
-    flat = edges.reshape(edges.shape[:-2] + (H * W,))
-    weights = (flat >= cfg.edge_threshold).astype(jnp.float32)
+    with jax.named_scope("compact" if cfg.compact else "vote"):
+        jj, ii = jnp.meshgrid(jnp.arange(W), jnp.arange(H))
+        xy = jnp.stack(
+            [jj.ravel(), ii.ravel(), jnp.ones(H * W, jnp.int32)], axis=1
+        ).astype(jnp.float32)
+        flat = edges.reshape(edges.shape[:-2] + (H * W,))
+        weights = (flat >= cfg.edge_threshold).astype(jnp.float32)
 
     return ops.hough_vote(
         xy, weights, jnp.asarray(trig), n_rho=n_rho, impl=cfg.impl,
@@ -350,7 +376,7 @@ def fused_hough(image: jax.Array, canny_cfg, cfg: HoughConfig,
     if max_edges is None:
         max_edges = ops.default_max_edges(H * W)
     return _fused_hough_tiered(image, canny_cfg, cfg, (int(max_edges),),
-                               theta_bins, corridors, scatter=scatter)
+                               theta_bins, corridors, scatter=scatter)[0]
 
 
 def fused_hough_tiered(image: jax.Array, canny_cfg, cfg: HoughConfig,
@@ -368,6 +394,19 @@ def fused_hough_tiered(image: jax.Array, canny_cfg, cfg: HoughConfig,
     Only a genuine overflow of the cap tier drops edges, exactly like the
     staged cap.
     """
+    return fused_hough_counted(image, canny_cfg, cfg, tiers, theta_bins,
+                               corridors, scatter=scatter)[0]
+
+
+def fused_hough_counted(image: jax.Array, canny_cfg, cfg: HoughConfig,
+                        tiers: tuple[int, ...] | None = None,
+                        theta_bins: jax.Array | None = None,
+                        corridors: jax.Array | None = None, *,
+                        scatter: bool = True
+                        ) -> tuple[jax.Array, jax.Array]:
+    """``fused_hough_tiered`` that also returns the per-frame edge counts
+    (after the corridor filter) its tier choice read: ``(votes,
+    counts)``."""
     H, W = image.shape[-2:]
     if not cfg.compact:
         tiers = (ops.default_max_edges(H * W),)
@@ -384,8 +423,10 @@ def _fused_hough_tiered(image: jax.Array, canny_cfg, cfg: HoughConfig,
                         tiers: tuple[int, ...],
                         theta_bins: jax.Array | None = None,
                         corridors: jax.Array | None = None, *,
-                        scatter: bool = True) -> jax.Array:
-    """Kernel A, exact-count tier choice, raster compaction, kernel B.
+                        scatter: bool = True
+                        ) -> tuple[jax.Array, jax.Array]:
+    """Kernel A, exact-count tier choice, raster compaction, kernel B:
+    ``(votes, per-frame edge counts)``.
 
     The exact post-corridor edge count (the same reduction as
     ``hough_transform_tiered``, on weights instead of the edge map) picks
@@ -399,21 +440,21 @@ def _fused_hough_tiered(image: jax.Array, canny_cfg, cfg: HoughConfig,
     H, W = image.shape[-2:]
     n_rho = rho_bins(H, W, cfg)
     trig = jnp.asarray(hough_trig(H, W, cfg))
-    w = ops.fused_weights(
-        image, corridors, cfg=canny_cfg, edge_threshold=cfg.edge_threshold,
-        impl=cfg.impl,
-    )
-    worst = (w > 0).sum(axis=-1).max().astype(jnp.int32)
-    idx = jnp.minimum(
-        sum((worst > t).astype(jnp.int32) for t in tiers),
-        len(tiers) - 1,
-    )
+    with jax.named_scope("canny"):
+        w = ops.fused_weights(
+            image, corridors, cfg=canny_cfg,
+            edge_threshold=cfg.edge_threshold, impl=cfg.impl,
+        )
+    with jax.named_scope("compact"):
+        counts = (w > 0).sum(axis=-1)
+        idx = tier_index(counts, tiers)
 
     def make(t):
         # theta_bins captured by closure (lax.switch branches may close
         # over tracers) so every branch keeps one operand signature.
         def branch(w):
-            cxy, cw = ops.compact_raster(w, width=W, max_edges=int(t))
+            with jax.named_scope("compact"):
+                cxy, cw = ops.compact_raster(w, width=W, max_edges=int(t))
             return ops.hough_vote(
                 cxy, cw, trig, n_rho=n_rho, impl=cfg.impl, compact=False,
                 theta_bins=theta_bins, scatter_back=scatter,
@@ -421,7 +462,7 @@ def _fused_hough_tiered(image: jax.Array, canny_cfg, cfg: HoughConfig,
 
         return branch
 
-    return jax.lax.switch(idx, [make(t) for t in tiers], w)
+    return jax.lax.switch(idx, [make(t) for t in tiers], w), counts
 
 
 def hough_paper_loop(edges: jax.Array, cfg: HoughConfig = HoughConfig()

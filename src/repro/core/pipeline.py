@@ -7,8 +7,7 @@ Three phases, exactly the paper's Table 1 decomposition:
   3. image generation  — render detected lines into an output frame.
 
 Phase 3 is implemented *and elidable* (``render_output=False``), reproducing
-the paper's 4.2x elision win.  ``detect_profiled`` produces the paper-style
-phase tables; ``benchmarks/`` consumes them.
+the paper's 4.2x elision win.
 
 Plan architecture (``core/plan.py``): a ``LineDetector`` no longer decides
 anything per call.  Each ``(height, width, batch-bucket)`` workload resolves
@@ -45,7 +44,7 @@ import jax.numpy as jnp
 
 from .canny import canny, estimate_edge_count
 from .hough import hough_transform, resolved_auto_config
-from .lines import get_lines, render_lines
+from .lines import get_lines
 from .plan import (  # noqa: F401  (re-exported API)
     DetectionPlan, DetectionResult, LUMA_WEIGHTS, PipelineConfig, PlanCache,
     batch_bucket, load_frame,
@@ -207,25 +206,6 @@ class LineDetector:
             in_flight = res
         if in_flight is not None:
             yield from split(*in_flight)
-
-    # --- full pipeline with paper-style phase profiling ----------------
-    def detect_profiled(
-        self, raw: jax.Array, profiler: PhaseProfiler | None = None,
-        repeats: int = 1,
-    ) -> tuple[DetectionResult, PhaseProfiler]:
-        prof = profiler or PhaseProfiler()
-        result = None
-        for _ in range(repeats):
-            image = prof.timeit("image_load", self.load, raw)
-            result = prof.timeit("line_detection", self.detect, image)
-            if self.cfg.render_output:
-                prof.timeit(
-                    "image_generation",
-                    lambda: render_lines(
-                        image.astype(jnp.uint8), result.lines, result.valid
-                    ),
-                )
-        return result, prof
 
     def detect_stage_profiled(
         self, image: jax.Array, repeats: int = 1

@@ -36,8 +36,8 @@ import numpy as np
 
 from .canny import CannyConfig, canny
 from .hough import (
-    HoughConfig, fused_hough, fused_hough_tiered, hough_transform,
-    hough_transform_tiered, max_edge_tiers,
+    HoughConfig, fused_hough, fused_hough_counted, hough_transform,
+    hough_transform_counted, max_edge_tiers,
 )
 from .lines import LinesConfig, get_lines, render_lines
 
@@ -65,6 +65,10 @@ class DetectionResult(NamedTuple):
     peaks: jax.Array      # (K, 2) (rho, theta)
     edges: jax.Array      # (H, W) uint8 Canny output
     rendered: jax.Array | None
+    # () int32 edge pixels the compaction tier choice counted (after the
+    # corridor filter on the fused path); tiered plans only, else None.
+    # Last and defaulted, so five-field constructions still work.
+    edge_count: jax.Array | None = None
 
 
 # BT.601 luma weights — the single source for BOTH grayscale conversions:
@@ -144,33 +148,42 @@ def _detect(cfg: PipelineConfig, image: jax.Array,
     counts the Canny edge pixels (max over a batch: the compaction buffer
     is shared) and ``lax.switch``-es the vote stage to the tier that holds
     them all; one compiled program per (shape, cfg), zero host
-    round-trips.  ``theta_bins`` (required iff ``cfg.hough.theta_band`` is
-    set) carries the prediction gate: the vote sweeps only those theta
-    bins (``core/tracking.py`` slides the gate frame to frame; the band
-    length is the static part, so the program never recompiles).
+    round-trips; the per-frame counts come back as ``edge_count``.
+    ``theta_bins`` (required iff ``cfg.hough.theta_band`` is set) carries
+    the prediction gate: the vote sweeps only those theta bins
+    (``core/tracking.py`` slides the gate frame to frame; the band length
+    is the static part, so the program never recompiles).
     ``corridors`` (required iff ``cfg.hough.corridors`` is set — fused
     path only) is the (C, 4) rho-window set that pre-filters edge pixels.
+
+    Every device op runs under one named scope — ``canny``, ``compact``
+    (the count, the tier choice and the prefix-sum scatter), ``vote``,
+    ``get_lines`` or ``render`` — which the compiled program's op
+    metadata carries, so a profile can put device time down to a stage.
     """
     H, W = image.shape[-2:]
+    counts = None
     if cfg.fused:
         # Fused hot path: no edge map ever materializes — kernel A emits
         # the compacted (corridor-filtered) edge list straight from the
         # frame, and the result's ``edges`` field is a zeros placeholder.
-        edges = jnp.zeros(image.shape, jnp.uint8)
+        with jax.named_scope("canny"):
+            edges = jnp.zeros(image.shape, jnp.uint8)
         if tiers is None:
             votes = fused_hough(image, cfg.canny, cfg.hough, theta_bins,
                                 corridors, scatter=False)
         else:
-            votes = fused_hough_tiered(image, cfg.canny, cfg.hough, tiers,
-                                       theta_bins, corridors,
-                                       scatter=False)
+            votes, counts = fused_hough_counted(
+                image, cfg.canny, cfg.hough, tiers, theta_bins, corridors,
+                scatter=False)
     else:
         if corridors is not None:
             raise ValueError(
                 "corridors is a fused-path argument; this plan is staged "
                 "(PipelineConfig.fused=False)"
             )
-        edges = canny(image, cfg.canny)
+        with jax.named_scope("canny"):
+            edges = canny(image, cfg.canny)
         # gated frames stay in band space end to end: the vote emits the
         # (n_rho, theta_band) accumulator and get_lines searches exactly
         # those columns, so the whole post-Canny stack scales with the band
@@ -178,15 +191,17 @@ def _detect(cfg: PipelineConfig, image: jax.Array,
             votes = hough_transform(edges, cfg.hough, theta_bins,
                                     scatter=False)
         else:
-            votes = hough_transform_tiered(edges, cfg.hough, tiers,
-                                           theta_bins, scatter=False)
-    lines, valid, peaks = get_lines(
-        votes, height=H, width=W, cfg=cfg.lines, theta_bins=theta_bins
-    )
+            votes, counts = hough_transform_counted(
+                edges, cfg.hough, tiers, theta_bins, scatter=False)
+    with jax.named_scope("get_lines"):
+        lines, valid, peaks = get_lines(
+            votes, height=H, width=W, cfg=cfg.lines, theta_bins=theta_bins
+        )
     rendered = None
     if cfg.render_output:
-        rendered = render_lines(image.astype(jnp.uint8), lines, valid)
-    return DetectionResult(lines, valid, peaks, edges, rendered)
+        with jax.named_scope("render"):
+            rendered = render_lines(image.astype(jnp.uint8), lines, valid)
+    return DetectionResult(lines, valid, peaks, edges, rendered, counts)
 
 
 def batch_bucket(n: int) -> int:
@@ -355,6 +370,7 @@ class DetectionPlan:
         return DetectionResult(
             res.lines[:n], res.valid[:n], res.peaks[:n], res.edges[:n],
             None if res.rendered is None else res.rendered[:n],
+            None if res.edge_count is None else res.edge_count[:n],
         )
 
     __call__ = run

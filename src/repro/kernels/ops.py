@@ -176,23 +176,25 @@ def hough_vote(xy, weights, trig, *, n_rho, impl=None, compact=False,
             )
         if max_edges is None:
             max_edges = default_max_edges(weights.shape[-1])
-        xy, weights = _compact_edges(xy, weights, max_edges=max_edges)
-    n_theta_full = trig.shape[1]
-    if theta_bins is not None:
-        trig = jnp.asarray(trig)[:, theta_bins]
-    if impl == "xla":
-        votes = ref.hough_vote(xy, weights, trig, n_rho=n_rho)
-    else:
-        votes = _hough_pallas(
-            xy, weights, trig, n_rho=n_rho, interpret=(impl == "interpret"),
-            **kw,
-        )
-    if theta_bins is not None and scatter_back:
-        votes = (
-            jnp.zeros(votes.shape[:-1] + (n_theta_full,), votes.dtype)
-            .at[..., theta_bins]
-            .set(votes)
-        )
+        with jax.named_scope("compact"):
+            xy, weights = _compact_edges(xy, weights, max_edges=max_edges)
+    with jax.named_scope("vote"):
+        n_theta_full = trig.shape[1]
+        if theta_bins is not None:
+            trig = jnp.asarray(trig)[:, theta_bins]
+        if impl == "xla":
+            votes = ref.hough_vote(xy, weights, trig, n_rho=n_rho)
+        else:
+            votes = _hough_pallas(
+                xy, weights, trig, n_rho=n_rho,
+                interpret=(impl == "interpret"), **kw,
+            )
+        if theta_bins is not None and scatter_back:
+            votes = (
+                jnp.zeros(votes.shape[:-1] + (n_theta_full,), votes.dtype)
+                .at[..., theta_bins]
+                .set(votes)
+            )
     return votes
 
 

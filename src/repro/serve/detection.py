@@ -96,6 +96,17 @@ top of the PR-3 throughput machinery:
     forward (whole EDF waves expire in one step), and NaN-poison frames
     (``INVALID_FRAME``, or a coast answer when the session can back one).
     Every injected fault resolves to an explicit terminal status.
+  * **Spans and stamps** — the served path records, while a JAX profiler
+    trace is active, host spans named ``service.*`` at each step that
+    does work (admission and its wait on the prefetch worker, the staging
+    itself on the worker thread, plan choice, ``device_put``, launch,
+    completion and its block, per-request split, tracker and
+    controller, drain), keyed by request uid and dispatch number; with no
+    trace active each is a closed ``TraceAnnotation``.  Every request
+    carries ``submitted_at``, ``admitted_at`` (slot taken),
+    ``dispatched_at`` (batch launched) and ``finished_at``, and the
+    service counts ``edge_pixels`` and ``vote_slots`` (how much of the
+    compaction buffers the vote swept held an edge).
 
 Plans come from ``core/plan.py``: one frozen ``DetectionPlan`` per bucket
 (plus its render-bound twin on demand).  ``benchmarks/service_suite.py``
@@ -141,12 +152,17 @@ from repro.core.control import (
     ControlConfig, LateralController, SteeringCommand,
 )
 from repro.core.geometry import CameraConfig, CameraGeometry
-from repro.core.hough import full_corridors
+from repro.core.hough import full_corridors, tier_for
 from repro.core.tracking import (
     LaneTracker, Track, TrackerConfig, tracks_as_peaks,
 )
 from repro.runtime.heartbeat import Heartbeat
 from repro.runtime.supervisor import WorkerFailure
+
+# Host spans of the served path: recorded while a profiler trace is active,
+# a closed annotation otherwise; keyword arguments are encoded only when
+# recorded.
+_span = jax.profiler.TraceAnnotation
 
 # Default resolution ladder: QQVGA-ish up to the paper's camera frame.
 DEFAULT_BUCKETS: tuple[tuple[int, int], ...] = (
@@ -428,6 +444,8 @@ class DetectionRequest:
     bucket: Optional[tuple[int, int]] = None
     downshift: int = 1                      # resolution divisor served at
     submitted_at: float = 0.0
+    admitted_at: float = 0.0                # slot taken
+    dispatched_at: float = 0.0              # its batch launched
     finished_at: float = 0.0
     deadline_at: Optional[float] = None     # absolute, on the service clock
     _staged: Optional[Union[Future, np.ndarray]] = dataclasses.field(
@@ -490,13 +508,14 @@ class _BucketGrid:
         self.est_measured = False   # True once a real dispatch fed the EMA
         self.slots: list[Optional[DetectionRequest]] = [None] * batch_size
         self.staged = np.zeros((batch_size, *shape), np.float32)
-        # (requests snapshot, async result, dispatch time, warm?, stall_s)
-        # awaiting completion; warm=False marks a compiling dispatch whose
-        # wall time must not feed the service-time EMA; stall_s > 0 marks
-        # an injected dispatch stall (completion lands late, EMA untouched)
+        # (requests snapshot, async result, dispatch time, warm?, stall_s,
+        # dispatch number) awaiting completion; warm=False marks a
+        # compiling dispatch whose wall time must not feed the
+        # service-time EMA; stall_s > 0 marks an injected dispatch stall
+        # (completion lands late, EMA untouched)
         self.in_flight: Optional[
             tuple[list[Optional[DetectionRequest]], DetectionResult,
-                  float, bool, float]
+                  float, bool, float, int]
         ] = None
 
     @property
@@ -621,6 +640,13 @@ def upscale_result(res: DetectionResult, factor: int,
         rendered = rendered.repeat(factor, axis=-3).repeat(factor, axis=-2)
         rendered = rendered[..., :height, :width, :]
     return DetectionResult(lines, valid, peaks, edges, rendered)
+
+
+def _stage_frame(uid: int, frame: np.ndarray, shape: tuple[int, int]
+                 ) -> np.ndarray:
+    """The prefetch worker's task: ``pad_to_bucket`` under a span."""
+    with _span("service.stage", uid=uid):
+        return pad_to_bucket(frame, shape)
 
 
 def _nan_poison(frame: np.ndarray) -> np.ndarray:
@@ -881,6 +907,10 @@ class DetectionService:
         self.rejected_invalid = 0     # NaN/corrupt frames refused
         self.dispatch_faults = 0      # requests failed by dispatch faults
         self.stager_deaths = 0        # prefetch-worker deaths observed
+        # vote slots: edge pixels (each capped at its batch's compaction
+        # tier) against tier x batch bucket, over tiered dispatches
+        self.edge_pixels = 0
+        self.vote_slots = 0
         # (shape, active slots, render) per dispatch — introspection for
         # tests/benchmarks; bounded so a long-running service cannot
         # accrete it without limit
@@ -1124,7 +1154,7 @@ class DetectionService:
                 self._loader = self._make_stager()
             try:
                 req._staged = self._loader.stage(
-                    pad_to_bucket, req.frame, req.bucket
+                    _stage_frame, req.uid, req.frame, req.bucket
                 )
                 return
             except WorkerFailure:
@@ -1298,7 +1328,8 @@ class DetectionService:
             return staged
         if staged is not None:            # a prefetch Future
             try:
-                return staged.result()
+                with _span("service.stage_wait", uid=req.uid):
+                    return staged.result()
             except WorkerFailure:
                 self._note_stager_death()
         return pad_to_bucket(req.frame, shape)
@@ -1317,27 +1348,32 @@ class DetectionService:
         prediction is exactly the right answer to one bad capture);
         otherwise the request refuses with ``INVALID_FRAME``.  Either
         way the slot stays free for the next queue entry."""
-        for shape in self.buckets:
-            grid = self.grids[shape]
-            q = self.queues[shape]
-            while q:
-                slot = grid.free_slot()
-                if slot is None:
-                    break
-                _, _, _, req = heapq.heappop(q)
-                # resolve staging BEFORE taking the slot: if the prefetch
-                # worker raised, the exception surfaces here with the
-                # request un-slotted (still PENDING) — never a DONE result
-                # silently computed from the slot's zeroed frame
-                staged = self._resolve_staged(req, grid.shape)
-                if self.validate_frames and not np.isfinite(staged).all():
-                    if not self._try_coast(req, self.clock()):
-                        self._refuse(req, RequestStatus.INVALID_FRAME,
-                                     self.clock())
-                        self.rejected_invalid += 1
-                    continue
-                grid.slots[slot] = req
-                grid.staged[slot] = staged
+        if not any(self.queues.values()):
+            return
+        with _span("service.admit", dispatch=self.dispatches):
+            for shape in self.buckets:
+                self._admit_into(self.grids[shape], self.queues[shape])
+
+    def _admit_into(self, grid: _BucketGrid, q: list) -> None:
+        while q:
+            slot = grid.free_slot()
+            if slot is None:
+                break
+            _, _, _, req = heapq.heappop(q)
+            # resolve staging BEFORE taking the slot: if the prefetch
+            # worker raised, the exception surfaces here with the
+            # request un-slotted (still PENDING) — never a DONE result
+            # silently computed from the slot's zeroed frame
+            staged = self._resolve_staged(req, grid.shape)
+            if self.validate_frames and not np.isfinite(staged).all():
+                if not self._try_coast(req, self.clock()):
+                    self._refuse(req, RequestStatus.INVALID_FRAME,
+                                 self.clock())
+                    self.rejected_invalid += 1
+                continue
+            grid.slots[slot] = req
+            grid.staged[slot] = staged
+            req.admitted_at = self.clock()
 
     def _reap(self) -> None:
         """Retire any in-flight batch whose result is already ready.
@@ -1367,8 +1403,12 @@ class DetectionService:
         offered deadline (hopeless-shed livelock).  Only back-to-back
         dispatches — the previous batch still in flight when the next one
         landed — can raise it."""
-        for g in self.grids.values():
-            self._complete(g, update_est=False)
+        busy = [g for g in self.grids.values() if g.in_flight is not None]
+        if not busy:
+            return
+        with _span("service.drain"):
+            for g in busy:
+                self._complete(g, update_est=False)
 
     def _complete(self, grid: _BucketGrid, *, update_est: bool = True
                   ) -> None:
@@ -1390,26 +1430,47 @@ class DetectionService:
         every sub-second budget."""
         if grid.in_flight is None:
             return
-        reqs, res, t_disp, was_warm, stall_s = grid.in_flight
+        reqs, res, t_disp, was_warm, stall_s, k = grid.in_flight
         grid.in_flight = None
-        jax.block_until_ready(res.lines)
-        if stall_s > 0.0 and hasattr(self.clock, "advance"):
-            # an injected dispatch stall: the device "took" stall_s extra
-            # seconds — model it on the virtual clock so the batch lands
-            # late, but keep the sample out of the EMA (a one-off stall is
-            # not evidence about steady-state service time)
-            self.clock.advance(stall_s)
-            was_warm = False
-        now = self.clock()
-        dt = now - t_disp
-        if was_warm and dt > 0.0 and (update_est or dt <= grid.est_s):
-            a = self.est_smoothing
-            grid.est_s = (1.0 - a) * grid.est_s + a * dt
-            grid.est_measured = True
-        for i, req in enumerate(reqs):
-            if req is None:
-                continue
-            assert not req.is_terminal, f"request {req.uid} answered twice"
+        with _span("service.complete", dispatch=k,
+                   uids=[r.uid for r in reqs if r is not None]):
+            with _span("service.block", dispatch=k):
+                jax.block_until_ready(res.lines)
+                if res.edge_count is not None:
+                    self._count_vote_slots(grid.plan,
+                                           jax.device_get(res.edge_count))
+            if stall_s > 0.0 and hasattr(self.clock, "advance"):
+                # an injected dispatch stall: the device "took" stall_s
+                # extra seconds — model it on the virtual clock so the
+                # batch lands late, but keep the sample out of the EMA (a
+                # one-off stall is not evidence about steady-state service
+                # time)
+                self.clock.advance(stall_s)
+                was_warm = False
+            now = self.clock()
+            dt = now - t_disp
+            if was_warm and dt > 0.0 and (update_est or dt <= grid.est_s):
+                a = self.est_smoothing
+                grid.est_s = (1.0 - a) * grid.est_s + a * dt
+                grid.est_measured = True
+            for i, req in enumerate(reqs):
+                if req is not None:
+                    self._answer(req, res, i, now)
+
+    def _count_vote_slots(self, plan: DetectionPlan,
+                          counts: np.ndarray) -> None:
+        """Add one tiered batch to ``edge_pixels`` and ``vote_slots``; the
+        tier comes from the counts by the device's own rule."""
+        tier = tier_for(int(counts.max()), plan.tiers)
+        self.edge_pixels += int(np.minimum(counts, tier).sum())
+        self.vote_slots += tier * plan.batch
+
+    def _answer(self, req: DetectionRequest, res: DetectionResult, i: int,
+                now: float) -> None:
+        """Slot ``i`` of a retired batch: split out the request's result,
+        advance its session's tracker and controller, stamp it."""
+        assert not req.is_terminal, f"request {req.uid} answered twice"
+        with _span("service.split", uid=req.uid):
             H, W = req.frame.shape[:2]
             want = req.render_output or self.cfg.render_output
             rendered = (
@@ -1432,29 +1493,31 @@ class DetectionService:
             else:
                 req.result = crop_result(per, H, W)
                 req.status = RequestStatus.DONE
-            if req.session_id is not None:
-                tracker = self.sessions.get(req.session_id)
-                if tracker is None:
-                    tracker = LaneTracker(self.tracker_cfg)
-                    self.sessions[req.session_id] = tracker
-                # slot order == admission order, and one batch is in
-                # flight per grid, so a session's frames advance its
-                # tracker in stream order (see DetectionRequest docstring).
-                # scale= widens the rho association gate for downshifted
-                # frames: the upscaled coarse detections must re-ground
-                # the existing tracks, not birth quantized twins —
-                # tracker state persists across resolution downshifts
+        if req.session_id is not None:
+            tracker = self.sessions.get(req.session_id)
+            if tracker is None:
+                tracker = LaneTracker(self.tracker_cfg)
+                self.sessions[req.session_id] = tracker
+            # slot order == admission order, and one batch is in
+            # flight per grid, so a session's frames advance its
+            # tracker in stream order (see DetectionRequest docstring).
+            # scale= widens the rho association gate for downshifted
+            # frames: the upscaled coarse detections must re-ground
+            # the existing tracks, not birth quantized twins —
+            # tracker state persists across resolution downshifts
+            with _span("service.track", uid=req.uid):
                 req.tracks = tracker.step(
                     np.asarray(req.result.peaks),
                     np.asarray(req.result.valid),
                     scale=req.downshift,
                 )
-                ctl = self._controller(req)
-                if ctl is not None:
-                    # steer from the smoothed tracks when the tracker
-                    # reports any, from the frame's raw detections
-                    # otherwise (session warmup) — the same fallback as
-                    # TrackedFrame.control_peaks
+            ctl = self._controller(req)
+            if ctl is not None:
+                # steer from the smoothed tracks when the tracker
+                # reports any, from the frame's raw detections
+                # otherwise (session warmup) — the same fallback as
+                # TrackedFrame.control_peaks
+                with _span("service.control", uid=req.uid):
                     if req.tracks:
                         req.steering = ctl.command(
                             *tracks_as_peaks(req.tracks)
@@ -1464,20 +1527,20 @@ class DetectionService:
                             np.asarray(req.result.peaks),
                             np.asarray(req.result.valid),
                         )
-                # a real frame re-grounds the tracker: the coast budget
-                # resets (see _try_coast)
-                self._session_coasts.pop(req.session_id, None)
-                slo = self._slo(req.session_id)
-                if req.downshift > 1:
-                    slo.served_downshift += 1
-                else:
-                    slo.served_full += 1
-            req.finished_at = now
-            if req.deadline_at is not None and now > req.deadline_at:
-                self.completed_late += 1
-                if req.session_id is not None:
-                    self._slo(req.session_id).late += 1
-            self.completed += 1
+            # a real frame re-grounds the tracker: the coast budget
+            # resets (see _try_coast)
+            self._session_coasts.pop(req.session_id, None)
+            slo = self._slo(req.session_id)
+            if req.downshift > 1:
+                slo.served_downshift += 1
+            else:
+                slo.served_full += 1
+        req.finished_at = now
+        if req.deadline_at is not None and now > req.deadline_at:
+            self.completed_late += 1
+            if req.session_id is not None:
+                self._slo(req.session_id).late += 1
+        self.completed += 1
 
     # --- union theta gate -----------------------------------------------
     def _union_gate(self, grid: _BucketGrid) -> Optional[np.ndarray]:
@@ -1656,16 +1719,18 @@ class DetectionService:
             r is not None and r.render_output for r in grid.slots
         )
         plan = grid.plan.with_render(True) if want_render else grid.plan
-        theta_bins = self._union_gate(grid)
-        corridors = None
-        if theta_bins is not None:
-            plan = plan.with_theta_band(self.gate_band)
-            # fused only under an engaged theta gate: both gates read the
-            # same tracker health, so a corridor-eligible grid is already
-            # gated — the fused plan is the gated plan's twin
-            corridors = self._union_corridors(grid)
-            if corridors is not None:
-                plan = plan.with_fused(self.fused_corridors)
+        k = self.dispatches
+        with _span("service.plan", dispatch=k):
+            theta_bins = self._union_gate(grid)
+            corridors = None
+            if theta_bins is not None:
+                plan = plan.with_theta_band(self.gate_band)
+                # fused only under an engaged theta gate: both gates read
+                # the same tracker health, so a corridor-eligible grid is
+                # already gated — the fused plan is the gated plan's twin
+                corridors = self._union_corridors(grid)
+                if corridors is not None:
+                    plan = plan.with_fused(self.fused_corridors)
         reqs = list(grid.slots)
         if self.faults is not None and self.faults.fails_dispatch(
                 self.dispatches):
@@ -1686,26 +1751,38 @@ class DetectionService:
             return True
         # every operand ships explicitly, so a warm dispatch transfers
         # nothing implicitly under the guard below
-        imgs = self.plans.put(grid.staged)
-        if theta_bins is not None:
-            theta_bins = self.plans.put(theta_bins)
-        if corridors is not None:
-            corridors = self.plans.put(corridors)
+        with _span("service.put", dispatch=k):
+            imgs = self.plans.put(grid.staged)
+            if theta_bins is not None:
+                theta_bins = self.plans.put(theta_bins)
+            if corridors is not None:
+                corridors = self.plans.put(corridors)
         warm_key = (grid.shape, plan.cfg.render_output,
                     plan.cfg.hough.theta_band, plan.cfg.fused)
         was_warm = warm_key in self._warmed
-        if was_warm:
-            with jax.transfer_guard("disallow"):
-                # async dispatch, batch k
-                res = plan.run(imgs, theta_bins, corridors)
-        else:
+        if not was_warm:
             # a compile takes seconds: retire the previous batch BEFORE it,
             # so the blocking-path EMA sample below cannot absorb compile
             # time (there is no overlap to preserve during a compile), and
             # est_s cannot inflate into shedding feasible traffic
             self._complete(grid)
-            res = plan.run(imgs, theta_bins, corridors)  # compiles
-            self._warmed.add(warm_key)
+        with _span("service.launch", dispatch=k,
+                   uids=[r.uid for r in reqs if r is not None]):
+            if was_warm:
+                with jax.transfer_guard("disallow"):
+                    # async dispatch, batch k
+                    res = plan.run(imgs, theta_bins, corridors)
+            else:
+                res = plan.run(imgs, theta_bins, corridors)  # compiles
+                self._warmed.add(warm_key)
+        launched = self.clock()
+        for req in reqs:
+            if req is not None:
+                req.dispatched_at = launched
+        if res.edge_count is not None:
+            # completion reads the counts for the vote slot counters: the
+            # copy starts now, so that read never waits on a transfer
+            res.edge_count.copy_to_host_async()
         if theta_bins is not None:
             self.gated_dispatches += 1
         if corridors is not None:
@@ -1722,7 +1799,7 @@ class DetectionService:
         self._complete(grid)
         stall = (self.faults.stall_for_dispatch(self.dispatches)
                  if self.faults is not None else 0.0)
-        grid.in_flight = (reqs, res, self.clock(), was_warm, stall)
+        grid.in_flight = (reqs, res, self.clock(), was_warm, stall, k)
         self.dispatches += 1
         self.dispatch_log.append((grid.shape, grid.active, want_render))
         grid.slots = [None] * self.batch_size   # slots free immediately
