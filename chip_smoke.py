@@ -30,8 +30,8 @@ and no compile may happen after warm-up.
 
 ``--chips 4`` runs only the router path: ``ShardedDetectionService`` with
 four replicas on the four chips of a 2x2 host, the same requests through
-one replica as the comparison, results on four distinct devices, and
-agreement between the two.
+one replica as the comparison, every replica on its own chip with at
+least one batch dispatched, and agreement between the two.
 
 Everything worth reading goes to stdout first; the last line is one JSON
 object ``{"ok": true, "device": {...}}``.  Any failed check exits non-zero
@@ -421,44 +421,51 @@ def single_chip(jax, seed: int) -> None:
 
 
 def four_chips(jax, seed: int) -> None:
-    from repro.serve.fleet import ShardedDetectionService
-
     devices = jax.devices()
     if len(devices) != 4:
         fail(f"--chips 4 needs 4 chips, JAX sees {len(devices)}")
     kw = service_kwargs()
     kw.update(gate_band=None, fused_corridors=None)
     frames = [f for f in phase_one_frames(seed) if f[2].shape == SHAPES[1]]
+    print(f"router phase: {len(frames)} sessionless "
+          f"{SHAPES[1][0]}x{SHAPES[1][1]} requests")
+    router_phase(devices, frames, service_config(), kw)
 
-    def serve(n_replicas, devs):
-        fleet = ShardedDetectionService(service_config(),
-                                        n_replicas=n_replicas,
+
+def router_phase(devices, frames, cfg, kw) -> None:
+    """Serve ``frames`` through one replica per device and through one
+    replica on the first device; every replica must sit on its own device
+    and dispatch work, and the two fleets must agree exactly."""
+    from repro.serve.fleet import ShardedDetectionService
+
+    def serve(devs):
+        fleet = ShardedDetectionService(cfg, n_replicas=len(devs),
                                         devices=devs, **kw)
         t0 = time.perf_counter()
         reqs = run_sessionless(fleet, frames, deadline_s=None)
         dt = time.perf_counter() - t0
-        replica_devs = [rep.service.device for rep in fleet.replicas]
+        served = [(rep.service.device, rep.service.dispatches)
+                  for rep in fleet.replicas]
         fleet.close()
-        require_served(reqs, f"{n_replicas} replica(s)")
-        print(f"  {n_replicas} replica(s): {len(reqs)} requests in "
+        require_served(reqs, f"{len(devs)} replica(s)")
+        print(f"  {len(devs)} replica(s): {len(reqs)} requests in "
               f"{dt:.3f} s (host clock, compiles included)")
-        return reqs, replica_devs
+        return reqs, served
 
-    print(f"router phase: {len(frames)} sessionless "
-          f"{SHAPES[1][0]}x{SHAPES[1][1]} requests")
-    four, four_devs = serve(4, list(devices))
-    one, _ = serve(1, [devices[0]])
-    result_devs = {d for r in four for d in r.result.peaks.devices()}
-    print(f"  replica devices: {[str(d) for d in four_devs]}")
-    print(f"  devices holding the 4-replica results: "
-          f"{sorted(str(d) for d in result_devs)}")
-    if len(set(four_devs)) != 4 or len(result_devs) != 4:
-        fail("the four replicas did not serve from four distinct devices")
-    same, _, n = compare(four, one, rho_bin=0.0, theta_bin=0.0)
-    print(f"agreement 4 replicas vs 1 replica: {same}/{n} requests with "
-          f"identical valid peaks")
+    many, served = serve(list(devices))
+    one, _ = serve([devices[0]])
+    print(f"  dispatches per replica: "
+          f"{[(str(d), n) for d, n in served]}")
+    if (len({d for d, _ in served}) != len(devices)
+            or not all(n > 0 for _, n in served)):
+        fail(f"the {len(devices)} replicas did not each serve from a "
+             f"device of their own")
+    same, _, n = compare(many, one, rho_bin=0.0, theta_bin=0.0)
+    print(f"agreement {len(devices)} replicas vs 1 replica: {same}/{n} "
+          f"requests with identical valid peaks")
     if same != n:
-        fail(f"{n - same} requests differ between 4 replicas and 1")
+        fail(f"{n - same} requests differ between {len(devices)} replicas "
+             f"and 1")
 
 
 def main() -> None:

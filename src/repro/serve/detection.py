@@ -100,8 +100,8 @@ top of the PR-3 throughput machinery:
     trace is active, host spans named ``service.*`` at each step that
     does work (admission and its wait on the prefetch worker, the staging
     itself on the worker thread, plan choice, ``device_put``, launch,
-    completion and its block, per-request split, tracker and
-    controller, drain), keyed by request uid and dispatch number; with no
+    completion, its block and its one fetch, per-request split, tracker
+    and controller, drain), keyed by request uid and dispatch number; with no
     trace active each is a closed ``TraceAnnotation``.  Every request
     carries ``submitted_at``, ``admitted_at`` (slot taken),
     ``dispatched_at`` (batch launched) and ``finished_at``, and the
@@ -433,7 +433,10 @@ class DetectionRequest:
     # batch in flight per grid), so the tracker sees the stream in order.
     session_id: Optional[str] = None
     policy: DegradationPolicy = DEFAULT_POLICY
-    # filled by the service
+    # filled by the service; the result's fields are numpy arrays, views
+    # of the host copy of the batch the request ran in, so a kept result
+    # keeps that batch's arrays alive (every slot's padded ``edges``;
+    # ``rendered`` only where this request asked for it)
     result: Optional[DetectionResult] = None
     tracks: Optional[list[Track]] = None    # smoothed tracks (sessions only)
     steering: Optional[SteeringCommand] = None  # lateral command (sessions
@@ -1436,9 +1439,6 @@ class DetectionService:
                    uids=[r.uid for r in reqs if r is not None]):
             with _span("service.block", dispatch=k):
                 jax.block_until_ready(res.lines)
-                if res.edge_count is not None:
-                    self._count_vote_slots(grid.plan,
-                                           jax.device_get(res.edge_count))
             if stall_s > 0.0 and hasattr(self.clock, "advance"):
                 # an injected dispatch stall: the device "took" stall_s
                 # extra seconds — model it on the virtual clock so the
@@ -1453,6 +1453,13 @@ class DetectionService:
                 a = self.est_smoothing
                 grid.est_s = (1.0 - a) * grid.est_s + a * dt
                 grid.est_measured = True
+            # the whole batch crosses to the host in one fetch (the copies
+            # started at launch); each request's answer is a numpy view of
+            # it, so retiring a batch runs no device program
+            with _span("service.fetch", dispatch=k):
+                res = jax.device_get(res)
+            if res.edge_count is not None:
+                self._count_vote_slots(grid.plan, res.edge_count)
             for i, req in enumerate(reqs):
                 if req is not None:
                     self._answer(req, res, i, now)
@@ -1507,8 +1514,7 @@ class DetectionService:
             # tracker state persists across resolution downshifts
             with _span("service.track", uid=req.uid):
                 req.tracks = tracker.step(
-                    np.asarray(req.result.peaks),
-                    np.asarray(req.result.valid),
+                    req.result.peaks, req.result.valid,
                     scale=req.downshift,
                 )
             ctl = self._controller(req)
@@ -1524,8 +1530,7 @@ class DetectionService:
                         )
                     else:
                         req.steering = ctl.command(
-                            np.asarray(req.result.peaks),
-                            np.asarray(req.result.valid),
+                            req.result.peaks, req.result.valid,
                         )
             # a real frame re-grounds the tracker: the coast budget
             # resets (see _try_coast)
@@ -1779,10 +1784,11 @@ class DetectionService:
         for req in reqs:
             if req is not None:
                 req.dispatched_at = launched
-        if res.edge_count is not None:
-            # completion reads the counts for the vote slot counters: the
-            # copy starts now, so that read never waits on a transfer
-            res.edge_count.copy_to_host_async()
+        # completion fetches every field to the host in one call: start the
+        # copies now, so they follow the device's work instead of the fetch
+        for field in res:
+            if field is not None:
+                field.copy_to_host_async()
         if theta_bins is not None:
             self.gated_dispatches += 1
         if corridors is not None:

@@ -114,7 +114,7 @@ def _service_spans(pd) -> list:
             if ev.name.startswith("service.")]
 
 
-def _traced(fn, tmp_path):
+def _trace(fn, tmp_path):
     from jax.profiler import ProfileData
 
     opts = jax.profiler.ProfileOptions()
@@ -126,7 +126,11 @@ def _traced(fn, tmp_path):
     finally:
         jax.profiler.stop_trace()
     path = sorted(glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True))
-    return _service_spans(ProfileData.from_file(path[-1]))
+    return ProfileData.from_file(path[-1])
+
+
+def _traced(fn, tmp_path):
+    return _service_spans(_trace(fn, tmp_path))
 
 
 def test_spans_name_each_step_that_works_and_no_empty_poll(tmp_path):
@@ -141,7 +145,8 @@ def test_spans_name_each_step_that_works_and_no_empty_poll(tmp_path):
     names = {n for n, _ in spans}
     assert names >= {"service.admit", "service.stage", "service.stage_wait",
                      "service.plan", "service.put", "service.launch",
-                     "service.complete", "service.block", "service.split",
+                     "service.complete", "service.block", "service.fetch",
+                     "service.split",
                      "service.track", "service.control", "service.drain"}
     uids = {r.uid for r in reqs}
     split = [a["uid"] for n, a in spans if n == "service.split"]
@@ -155,4 +160,39 @@ def test_spans_name_each_step_that_works_and_no_empty_poll(tmp_path):
     # nothing queued, slotted or in flight: a step does no work
     assert _traced(lambda: [svc.step() for _ in range(20)],
                    tmp_path / "idle") == []
+    svc.close()
+
+
+
+def _timed_spans(pd, name) -> list:
+    return [(dict(ev.stats), ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name == name]
+
+
+def test_one_fetch_per_retired_batch_inside_its_completion(tmp_path):
+    svc = _tracked_service()
+    svc.warm_up()
+    # one frame in a grid of four: the step admits it and retires nothing
+    first = DetectionRequest(uid=100,
+                             frame=make_scenario("straight", *HW).image)
+    admit = _traced(lambda: (svc.submit(first), svc.step()),
+                    tmp_path / "admit")
+    assert "service.admit" in {n for n, _ in admit}
+    assert not {"service.complete", "service.fetch"} & {n for n, _ in admit}
+    assert svc.dispatches == 0
+
+    pd = _trace(lambda: _drive(svc, n=4), tmp_path / "work")
+    assert first.ok
+    fetches = _timed_spans(pd, "service.fetch")
+    completes = {a["dispatch"]: (t0, t1)
+                 for a, t0, t1 in _timed_spans(pd, "service.complete")}
+    # every dispatch retired inside the window, each with one fetch
+    assert sorted(a["dispatch"] for a, _, _ in fetches) == list(
+        range(svc.dispatches))
+    assert sorted(completes) == list(range(svc.dispatches))
+    for a, t0, t1 in fetches:
+        c0, c1 = completes[a["dispatch"]]
+        assert c0 <= t0 <= t1 <= c1
     svc.close()
