@@ -1,7 +1,10 @@
 """Drive one cell through ``DetectionService`` and measure it.
 
 The served entry is the one a user calls: ``DetectionService.submit``,
-then ``step()`` until the request is terminal.  The harness sends the
+then ``step()`` until the request is terminal.  A configuration with
+``"replicas": N`` (N > 1) deploys N of them behind the program's router,
+``ShardedDetectionService``, one on each of the cell's first N chips; the
+entry is then the router's ``submit`` and ``step``.  The harness sends the
 cell's traffic (``traffic/generator.py``) from this one thread, steps the
 service between sends, and records for every frame when it was due, when
 it was sent and when its terminal answer came.  Around each call into the
@@ -11,7 +14,9 @@ into the profiler's trace in a traced run.
 The program under test is imported from ``src/repro`` of the checkout;
 nothing else of it is used but its service, its counters and, at the call
 into the plan (``PlanCache.put``), the gate and corridors each dispatch
-ships, which the reference needs.
+ships, which the reference needs.  Behind a router every replica is read
+alike (``services``): counters are summed over the replicas and each
+replica's dispatches are recorded at its own plan cache.
 """
 
 from __future__ import annotations
@@ -22,7 +27,7 @@ import os
 import sys
 import time
 from pathlib import Path
-from typing import Optional
+from typing import Optional, Sequence
 
 import numpy as np
 
@@ -34,6 +39,9 @@ COUNTERS = ("dispatches", "completed", "gated_dispatches",
             "fused_dispatches", "completed_late", "shed_deadline",
             "downshifted", "served_coast", "rejected_queue_full",
             "rejected_invalid")
+# the router's own counters, read beside the sums where there is a router
+ROUTER_COUNTERS = ("routed", "session_migrations", "session_failovers",
+                   "requeued")
 
 
 class BenchError(RuntimeError):
@@ -72,13 +80,33 @@ def _plain(v):
     return tuple(_plain(x) for x in v) if isinstance(v, list) else v
 
 
-def build_service(config: dict):
+def require_replicas(config: dict, chips: int, devices: Sequence) -> None:
+    """A configuration deploys ``"replicas": N`` services (1 without the
+    key), one on each of the cell's chips: a cell whose ``chips`` is not
+    N, or a host with fewer than N devices, is refused."""
+    n = config.get("replicas", 1)
+    if type(n) is not int or n < 1:
+        raise BenchError(f"replicas {n!r}: a whole number, at least 1")
+    if n != chips:
+        raise BenchError(f"the cell's chips ({chips}) differ from the "
+                         f"configuration's replicas ({n})")
+    if len(devices) < n:
+        raise BenchError(f"the configuration deploys {n} replicas, more "
+                         f"than the devices JAX sees ({len(devices)})")
+
+
+def build_service(config: dict, devices: Sequence):
     """The service as the configuration deploys it.  Every key of its
     ``service`` object is a ``DetectionService`` option, passed as it
     stands, except two that name objects: ``hough`` (the
     ``HoughConfig`` fields of the ``PipelineConfig``) and ``steering``
     (true: the default ``ControlConfig``).  Every other option is the
-    program's default."""
+    program's default.
+
+    A top-level ``"replicas": N`` above 1 deploys N such services behind
+    ``ShardedDetectionService`` with the router's defaults, one on each
+    of ``devices[:N]`` (``require_replicas`` checks N first)."""
+    n = config.get("replicas", 1)
     from repro.core import ControlConfig, HoughConfig, PipelineConfig
     from repro.serve.detection import DetectionService
 
@@ -86,7 +114,50 @@ def build_service(config: dict):
     cfg = PipelineConfig(hough=HoughConfig(**kw.pop("hough", {})))
     if kw.pop("steering", False):
         kw["steering"] = ControlConfig()
-    return DetectionService(cfg, **kw)
+    if n == 1:
+        return DetectionService(cfg, **kw)
+    from repro.serve.fleet import ShardedDetectionService
+
+    return ShardedDetectionService(cfg, n_replicas=n,
+                                   devices=list(devices[:n]), **kw)
+
+
+def services(svc) -> list:
+    """The ``DetectionService``s that serve: ``svc`` itself, or each
+    replica's behind a router, in replica order."""
+    replicas = getattr(svc, "replicas", None)
+    return [svc] if replicas is None else [r.service for r in replicas]
+
+
+def device_ids(svc, default) -> list[int]:
+    """The id of the device each service dispatches to (``default``: the
+    device a service without one of its own uses)."""
+    return [(default if s.device is None else s.device).id
+            for s in services(svc)]
+
+
+def counters(svc, names: Sequence[str] = COUNTERS, missing=None) -> dict:
+    """Each counter of ``names`` summed over the services; a name a
+    service lacks raises, or reads ``missing`` where that is given.
+    Behind a router also the router's own counters and
+    ``replica_dispatches``, each replica's dispatch count."""
+    svcs = services(svc)
+
+    def read(s, k):
+        return getattr(s, k) if missing is None else getattr(s, k, missing)
+
+    out = {k: sum(read(s, k) for s in svcs) for k in names}
+    if svcs[0] is not svc:
+        out.update({k: getattr(svc, k) for k in ROUTER_COUNTERS})
+        out["replica_dispatches"] = [s.dispatches for s in svcs]
+    return out
+
+
+def counted_since(before: dict, after: dict) -> dict:
+    """What each counter of ``counters`` counted between two readings."""
+    return {k: ([a - b for a, b in zip(v, before[k])]
+                if isinstance(v, list) else v - before[k])
+            for k, v in after.items()}
 
 
 class CompileCounter:
@@ -120,35 +191,42 @@ class CompileCounter:
 class Dispatch:
     at: float
     uids: list
+    replica: int = 0
     bins: Optional[np.ndarray] = None
     cors: Optional[np.ndarray] = None
 
 
 class DispatchRecorder:
     """Records, at the call into the plan, which requests each dispatch
-    carried and the gate and corridors it shipped.  ``step`` puts the slot
-    buffer first, then the gate, then the corridors, then runs the plan."""
+    carried and the gate and corridors it shipped, at each replica's own
+    plan cache.  ``step`` puts the slot buffer first, then the gate, then
+    the corridors, then runs the plan; a replica's step runs whole before
+    the next replica's."""
 
     def __init__(self, svc):
-        self.svc = svc
         self.log: list[Dispatch] = []
-        self._put = svc.plans.put
-        svc.plans.put = self.put
+        for i, s in enumerate(services(svc)):
+            s.plans.put = self._hook(i, s)
 
-    def put(self, x):
-        for g in self.svc.grids.values():
-            if x is g.staged:
-                self.log.append(Dispatch(
-                    time.perf_counter(),
-                    [r.uid for r in g.slots if r is not None]))
-                break
-        else:
-            if self.log and isinstance(x, np.ndarray):
-                if x.dtype == np.int32 and x.ndim == 1:
-                    self.log[-1].bins = x.copy()
-                elif x.ndim == 2 and x.shape[-1] == 4:
-                    self.log[-1].cors = x.copy()
-        return self._put(x)
+    def _hook(self, replica: int, svc):
+        put = svc.plans.put
+
+        def record(x):
+            for g in svc.grids.values():
+                if x is g.staged:
+                    self.log.append(Dispatch(
+                        time.perf_counter(),
+                        [r.uid for r in g.slots if r is not None], replica))
+                    break
+            else:
+                if self.log and isinstance(x, np.ndarray):
+                    if x.dtype == np.int32 and x.ndim == 1:
+                        self.log[-1].bins = x.copy()
+                    elif x.ndim == 2 and x.shape[-1] == 4:
+                        self.log[-1].cors = x.copy()
+            return put(x)
+
+        return record
 
     def by_uid(self) -> dict:
         return {u: d for d in self.log for u in d.uids}
@@ -284,22 +362,26 @@ def warm(svc, driver: Driver, traffic: generator.Traffic) -> None:
     Tracked traffic can take every binding of every bucket (the gate and
     corridors of a warm tracker, a ladder downshift under load), so all
     are compiled, and one batch is served downshifted into each smaller
-    bucket so the result path at that shape is built too.  Sessionless
-    traffic without deadlines takes only the full sweep at its own shape.
-    Then the cell's traffic runs until two grids of answers have come
-    back (the first answers build the result path) and ``warm_s`` more.
+    bucket so the result path at that shape is built too; behind a router
+    each replica does so on its own device, submitted to it directly.
+    Sessionless traffic without deadlines takes only the full sweep at
+    its own shape.  Then the cell's traffic runs until two grids of
+    answers a replica have come back (the first answers build the result
+    path) and ``warm_s`` more.
     """
+    svcs = services(svc)
     if traffic.kind == "open_streams":
-        svc.warm_up()
         frame = traffic.streams[0].frame(0)[1]
-        native = svc.bucket_for(frame)
-        for bucket in svc.buckets:
-            if bucket[0] < native[0]:
-                for i in range(svc.batch_size):
-                    svc.submit(driver.request(uid=-1 - i, frame=frame),
-                               force_bucket=bucket)
-                svc.run()
-    need = 2 * svc.batch_size
+        for s in svcs:
+            s.warm_up()
+            native = s.bucket_for(frame)
+            for bucket in s.buckets:
+                if bucket[0] < native[0]:
+                    for i in range(s.batch_size):
+                        s.submit(driver.request(uid=-1 - i, frame=frame),
+                                 force_bucket=bucket)
+                    s.run()
+    need = 2 * svcs[0].batch_size * len(svcs)
     while sum(r.req.is_terminal for r in driver.sent) < need:
         driver.run_until(time.perf_counter() + 0.25)
     driver.run_until(time.perf_counter() + traffic.warm_s)

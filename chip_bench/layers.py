@@ -17,13 +17,15 @@ KERNELS = {
 
 
 def device_idle_pct(run):
+    """Mean idle share over the chips that served."""
     tr = run["trace"]
     return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
 
 
 def device_ms_per_frame(run):
-    n = run["answered_in_window"]
-    return 1e3 * run["trace"]["busy_s"] / n if n else None
+    """Device time a frame costs, summed over the chips that served."""
+    tr, n = run["trace"], run["answered_in_window"]
+    return 1e3 * tr["busy_s"] * tr["n_devices"] / n if n else None
 
 
 def roofline(kernel):

@@ -105,16 +105,20 @@ def run_cell(args, *, root: Path = ROOT, require_tpu: bool = True,
 
         enable_compile_cache()
     traffic = generator.build(config, mix, args.seed)
-    svc = harness.build_service(config)
+    devices = jax.devices()
+    harness.require_replicas(config, wl["chips"], devices)
+    svc = harness.build_service(config, devices)
     driver = harness.Driver(svc, traffic, trace=bool(args.trace))
     harness.warm(svc, driver, traffic)
     table = None
     if args.trace:
         t_texts = time.perf_counter()
-        table = program_trace.scope_table(program_texts(svc))
+        # replicas run the same programs, each on its own device
+        table = program_trace.scope_table(
+            program_texts(harness.services(svc)[0]))
         print(f"scope table from the compiled programs in "
               f"{time.perf_counter() - t_texts:.1f} s", file=sys.stderr)
-    before = {k: getattr(svc, k, 0) for k in COUNTERS}
+    before = harness.counters(svc, COUNTERS, missing=0)
 
     gc.collect()
     gc.freeze()
@@ -135,7 +139,8 @@ def run_cell(args, *, root: Path = ROOT, require_tpu: bool = True,
         driver.run_until(t0 + args.seconds)
         t1 = time.perf_counter()
     gc.unfreeze()
-    counters = {k: getattr(svc, k, 0) - before[k] for k in COUNTERS}
+    counters = harness.counted_since(
+        before, harness.counters(svc, COUNTERS, missing=0))
     t_end = driver.finish()
     svc.close()
 
@@ -155,7 +160,8 @@ def run_cell(args, *, root: Path = ROOT, require_tpu: bool = True,
               "failed": latency.failed(frames)}
     if args.trace:
         path = trace_reduce.find_xplane(trace_dir)
-        run["trace"] = trace_reduce.reduce_file(path)
+        run["trace"] = trace_reduce.reduce_file(
+            path, device_ids=harness.device_ids(svc, devices[0]))
         run["program"] = program_trace.reduce_file(path, table)
         if args.keep:
             keep = Path(args.keep)

@@ -18,8 +18,11 @@ a profiler trace of the window and reports the cell's per-layer metrics.
 The last line of standard output is one JSON object; the numbers compared
 for ``correct`` come last in it (``checks``) and as the last lines of
 standard error.  A host without a TPU, a forced kernel impl other than
-Pallas, or a directory without the program exits non-zero and prints no
-result.
+Pallas, a directory without the program, or a configuration whose
+``replicas`` outnumber the cell's chips or the host's devices exits
+non-zero and prints no result.  ``device`` reports the cell's chips: their
+count, and the peak memory of the fullest; the trace is read on the chips
+the service dispatches to.
 """
 
 from __future__ import annotations
@@ -81,11 +84,13 @@ def run_cell(args, *, root: Path = ROOT, require_tpu: bool = True,
         enable_compile_cache()
     compiles = harness.CompileCounter(jax)
     traffic = generator.build(config, mix, args.seed, streams=streams)
-    svc = harness.build_service(config)
+    devices = jax.devices()
+    harness.require_replicas(config, wl["chips"], devices)
+    svc = harness.build_service(config, devices)
     recorder = harness.DispatchRecorder(svc)
     driver = harness.Driver(svc, traffic, trace=bool(args.trace))
     harness.warm(svc, driver, traffic)
-    before = {k: getattr(svc, k) for k in harness.COUNTERS}
+    before = harness.counters(svc)
     built_setup = compiles.snapshot()
     n_log = len(recorder.log)
 
@@ -111,15 +116,19 @@ def run_cell(args, *, root: Path = ROOT, require_tpu: bool = True,
     gc.unfreeze()
     in_window = compiles.snapshot().get("built", 0) - built_setup.get(
         "built", 0)
-    counters = {k: getattr(svc, k) - before[k] for k in harness.COUNTERS}
+    counters = harness.counted_since(before, harness.counters(svc))
     window_dispatches = [d for d in recorder.log[n_log:] if d.at < t1]
     t_end = driver.finish()
 
-    dev = jax.devices()[0]
-    stats = dev.memory_stats() or {}
+    # the cell's chips: the peak is the fullest chip's
+    chips = devices[:wl["chips"]]
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in chips]
+    peaks = [b for b in peaks if b is not None]
+    dev = chips[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(jax.devices()),
-              "memory_peak_bytes": stats.get("peak_bytes_in_use")}
+              "count": len(chips),
+              "memory_peak_bytes": max(peaks) if peaks else None}
 
     recs = harness.frames_in(driver, t0, t1)
     frames = harness.as_latency_frames(recs, traffic.deadline_s)
@@ -145,7 +154,9 @@ def run_cell(args, *, root: Path = ROOT, require_tpu: bool = True,
     else:
         wanted = registry.per_layer_for(bench, wl["name"])
         t_reduce = time.perf_counter()
-        reduced = trace_reduce.reduce_file(trace_reduce.find_xplane(trace_dir))
+        reduced = trace_reduce.reduce_file(
+            trace_reduce.find_xplane(trace_dir),
+            device_ids=harness.device_ids(svc, devices[0]))
         print(f"trace reduced in {time.perf_counter() - t_reduce:.1f} s",
               file=sys.stderr)
         shutil.rmtree(trace_dir, ignore_errors=True)

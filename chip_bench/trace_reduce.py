@@ -4,11 +4,12 @@ device time and idle gaps, with ``jax.profiler.ProfileData`` alone.
 Device planes are ``/device:TPU:<n>``; their ``XLA Ops`` line holds one
 event per operation run on the chip.  The window is the host span
 ``bench.window`` that the harness writes around its measured loop; device
-intervals are clipped to it.  Busy time is the union of the op intervals;
-an idle gap is a stretch of the window in which no op runs, named by the
-harness span on the host that covers its midpoint (``bench.submit``,
-``bench.step``) or ``generator`` where none does (the load generator
-between sends).
+intervals are clipped to it.  Where the caller names the devices the
+service dispatches to, only their planes are read.  Busy time is the union
+of the op intervals; an idle gap is a stretch of the window in which no op
+runs, named by the harness span on the host that covers its midpoint
+(``bench.submit``, ``bench.step``) or ``generator`` where none does (the
+load generator between sends).
 """
 
 from __future__ import annotations
@@ -75,16 +76,21 @@ def gaps(intervals, t0: float, t1: float) -> list[tuple[float, float]]:
     return [(a, b) for a, b in out if b > a]
 
 
-def reduce_profile(pd, *, top: int = 10) -> dict:
+def reduce_profile(pd, *, device_ids: Optional[Iterable[int]] = None,
+                   top: int = 10) -> dict:
     """``{window_s, busy_s, n_devices, ops: {label: s}, idle_gaps}`` from a
-    loaded ``ProfileData``.  Times in seconds; busy time averaged over the
-    devices."""
+    loaded ``ProfileData``, over the planes of ``device_ids`` (every
+    device plane where that is None).  Times in seconds; busy time
+    averaged over those devices, op time summed over them, idle gaps
+    those of the first."""
     window: Optional[tuple[float, float]] = None
     host: list[tuple[float, float, str]] = []
     devices = []
+    wanted = None if device_ids is None else set(device_ids)
     for plane in pd.planes:
         if DEVICE_PLANE.match(plane.name):
-            devices.append(plane)
+            if wanted is None or int(plane.name.rsplit(":", 1)[1]) in wanted:
+                devices.append(plane)
             continue
         if not plane.name.startswith("/host:"):
             continue
@@ -98,7 +104,8 @@ def reduce_profile(pd, *, top: int = 10) -> dict:
     if window is None:
         raise ValueError(f"no {WINDOW_SPAN!r} span in the trace")
     if not devices:
-        raise ValueError("no /device:TPU plane in the trace")
+        which = "" if wanted is None else f" of devices {sorted(wanted)}"
+        raise ValueError(f"no /device:TPU plane{which} in the trace")
     t0, t1 = window
     ops: collections.Counter = collections.Counter()
     busy_ns = 0.0

@@ -200,7 +200,9 @@ def test_program_texts_hold_every_scope(bench_copy):
 
     cell = reg.find_cell("tiny.offline", bench_copy)
     harness.prepare_environment(bench_copy)
-    svc = harness.build_service(cell["config"])
+    import jax
+
+    svc = harness.build_service(cell["config"], jax.devices())
     svc.detect_many([cell_frame(cell)] * svc.batch_size)
     texts = program_run.program_texts(svc)
     svc.close()

@@ -60,6 +60,40 @@ def test_idle_gaps_are_named_by_the_host_span_around_them():
     assert r["idle_gaps"][1] == ["step", pytest.approx(50e-9)]
 
 
+def four_chips():
+    """The window of ``profile`` on four TPU planes: chip n runs one op
+    of 100 * (n + 1) ns from 1100."""
+    host = profile().planes[0]
+    devs = [NS(name=f"/device:TPU:{n}", lines=[NS(name="XLA Ops", events=[
+        ev(f"fusion.{n}", 1100, 100 * (n + 1))])]) for n in range(4)]
+    return NS(planes=[host] + devs)
+
+
+def test_only_the_planes_of_the_services_devices_are_reduced():
+    r = trace_reduce.reduce_profile(four_chips(), device_ids=[1, 3])
+    assert r["n_devices"] == 2
+    assert r["busy_s"] == pytest.approx((200e-9 + 400e-9) / 2)
+    assert set(r["ops"]) == {"fusion.1", "fusion.3"}
+    # the first held chip's gaps: [1000,1100] in no span, [1300,2000]
+    assert [s for _, s in r["idle_gaps"]] == [pytest.approx(700e-9),
+                                              pytest.approx(100e-9)]
+    every = trace_reduce.reduce_profile(four_chips())
+    assert every["n_devices"] == 4
+    assert every["busy_s"] == pytest.approx(250e-9)
+    with pytest.raises(ValueError):
+        trace_reduce.reduce_profile(four_chips(), device_ids=[7])
+
+
+def test_device_time_per_frame_sums_over_the_held_chips():
+    from chip_bench import layers
+
+    r = trace_reduce.reduce_profile(four_chips(), device_ids=[1, 3])
+    run = {"trace": r, "answered_in_window": 2}
+    # 600 ns of device time over both chips for 2 frames
+    assert layers.device_ms_per_frame(run) == pytest.approx(300e-9 * 1e3)
+    assert layers.device_idle_pct(run) == pytest.approx(70.0)
+
+
 def test_union_and_gaps_helpers():
     assert trace_reduce.union_length([(0, 2), (1, 3), (5, 6)]) == 4
     assert trace_reduce.gaps([(1, 2), (4, 5)], 0, 6) == [(0, 1), (2, 4),
@@ -96,3 +130,16 @@ def test_recorded_v5e_trace():
     idle = sum(s for _, s in r["idle_gaps"])
     assert idle <= r["window_s"] - r["busy_s"] + 1e-9
     assert set(r["idle_by_cause"]) <= {"step", "submit", "generator"}
+
+
+def test_recorded_v5e_trace_on_its_one_chip():
+    """Naming the one chip the service held reads the whole trace."""
+    import gzip
+
+    from jax.profiler import ProfileData
+
+    raw = gzip.decompress((FIXTURE.parent / (FIXTURE.name + ".xplane.pb.gz"))
+                          .read_bytes())
+    pd = ProfileData.from_serialized_xspace(raw)
+    assert trace_reduce.reduce_profile(pd, device_ids=[0]) == \
+        trace_reduce.reduce_profile(pd)
