@@ -90,38 +90,63 @@ def test_conv2d_gemm_batched_one_launch(rng):
                                rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("n_pix,n_theta,n_rho", [(64, 45, 60), (200, 180, 150)])
-def test_hough_vote(rng, n_pix, n_theta, n_rho):
-    xy = rng.uniform(0, 40, (n_pix, 3)).astype(np.float32)
-    xy[:, 2] = 1.0
-    w = (rng.uniform(size=n_pix) > 0.4).astype(np.float32)
-    trig = rng.uniform(-1, 1, (3, n_theta)).astype(np.float32)
-    trig[2] = n_rho / 2.5
-    got = hough_vote(jnp.asarray(xy), jnp.asarray(w), jnp.asarray(trig),
-                     n_rho=n_rho, interpret=True, br=32, bp=64)
-    want = ref.hough_vote(jnp.asarray(xy), jnp.asarray(w), jnp.asarray(trig),
-                          n_rho=n_rho)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-3)
+def _vote_inputs(rng, n_pix, n_theta, n_rho, edge_frac=0.15, batch=None,
+                 per_frame=False):
+    """Vote operands on which every backend computes the same rho.
 
-
-def _vote_inputs(rng, n_pix, n_theta, n_rho, edge_frac=0.15, batch=None):
-    xy = rng.uniform(0, 40, (n_pix, 3)).astype(np.float32)
-    xy[:, 2] = 1.0
+    Integer coordinates in ``[0, n_rho / 2)`` and trig rows on a 2**-13
+    grid make every product and sum exact in f32, fused or not, so votes
+    match bit for bit.  rho spans about ``[-0.6, 1.4] x n_rho``: bins below
+    0 and at or past ``n_rho`` cast no vote.  Theta 0 (``off - x``) and
+    theta 1 (``x + y + off``) land exactly on bin edges, and pixels 0-3
+    are edges that sit on bins -1, 0, n_rho - 1 and n_rho.
+    """
+    off = np.floor(0.4 * n_rho)
+    xy = np.ones((n_pix, 3), np.float32)
+    xy[:, :2] = rng.integers(0, n_rho // 2, (n_pix, 2))
+    # theta 0: off - x = -1, 0; theta 1: x + y + off = n_rho - 1, n_rho
+    xy[:4, :2] = [[off + 1, 0], [off, 0],
+                  [(n_rho - off - 1) // 2, (n_rho - off) // 2],
+                  [(n_rho - off) // 2, (n_rho - off + 1) // 2]]
+    trig = np.round(rng.uniform(-1, 1, (3, n_theta)) * 8192) / 8192
+    trig[:2, :2] = [[-1.0, 1.0], [0.0, 1.0]]
+    trig[2] = off
+    trig = trig.astype(np.float32)
     shape = (batch, n_pix) if batch else (n_pix,)
     w = (rng.uniform(size=shape) > 1 - edge_frac).astype(np.float32)
-    trig = rng.uniform(-1, 1, (3, n_theta)).astype(np.float32)
-    trig[2] = n_rho / 2.5
+    w[..., :4] = 1.0
+    if per_frame:  # each frame its own pixels, shifted a bin or two
+        xy = np.stack([xy + [i, i % 2, 0] for i in range(batch)])
     return jnp.asarray(xy), jnp.asarray(w), jnp.asarray(trig)
 
 
-def test_hough_vote_batched(rng):
-    """Shared raster coords + (N, P) weights lower as one batched kernel."""
-    xy, w, trig = _vote_inputs(rng, 200, 90, 150, edge_frac=0.3, batch=3)
-    got = hough_vote(xy, w, trig, n_rho=150, interpret=True,
-                     br=32, bp=64, bt=32)
-    assert got.shape == (3, 150, 90)
-    want = ref.hough_vote(xy, w, trig, n_rho=150)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-3)
+@pytest.mark.parametrize("n_pix,n_theta,n_rho", [
+    (64, 45, 60), (200, 180, 150), (200, 40, 60), (200, 180, 60),
+    (200, 40, 300), (200, 45, 300), (200, 180, 300), (300, 40, 801),
+    (300, 45, 801), (300, 180, 801),
+])
+def test_hough_vote(rng, n_pix, n_theta, n_rho):
+    """One frame: the MXU vote == the scatter-add oracle, bit for bit, over
+    several 128-bin high parts of rho and several pixel blocks."""
+    xy, w, trig = _vote_inputs(rng, n_pix, n_theta, n_rho, edge_frac=0.6)
+    got = hough_vote(xy, w, trig, n_rho=n_rho, interpret=True, bp=128)
+    want = ref.hough_vote(xy, w, trig, n_rho=n_rho)
+    assert got.shape == (n_rho, n_theta)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("n_theta,n_rho", [(90, 150), (40, 801), (180, 300)])
+@pytest.mark.parametrize("per_frame", [False, True])
+def test_hough_vote_batched(rng, per_frame, n_theta, n_rho):
+    """Shared raster coords or per-frame pixel sets + (N, P) weights lower
+    as one batched kernel."""
+    xy, w, trig = _vote_inputs(rng, 200, n_theta, n_rho, edge_frac=0.3,
+                               batch=3, per_frame=per_frame)
+    got = hough_vote(xy, w, trig, n_rho=n_rho, interpret=True, bp=128,
+                     bt=16)
+    assert got.shape == (3, n_rho, n_theta)
+    want = ref.hough_vote(xy, w, trig, n_rho=n_rho)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_compact_edges_matches_ref(rng):

@@ -76,16 +76,27 @@ def test_conv2d_gemm_compiles(one_chip, hw, batch):
     _compile(step, ((batch,) + hw, jnp.float32), sharding=one_chip)
 
 
-@pytest.mark.parametrize("band", [None, 40])
-@pytest.mark.parametrize("batch", BATCHES)
-@pytest.mark.parametrize("hw", BUCKETS)
-def test_hough_vote_compiles(one_chip, hw, batch, band):
+# Every bucket and batch at the cap tier, full sweep and gate; then the
+# tiers the 480x640 cells dispatch (base 512, doubling) at batch 4.
+_VOTE_CASES = [
+    pytest.param(hw, batch, band, None, id=f"hw{i}-{batch}-{band}")
+    for i, hw in enumerate(BUCKETS)
+    for batch in BATCHES
+    for band in (None, 40)
+] + [
+    pytest.param((480, 640), 4, None, tier, id=f"hw1-4-None-tier{tier}")
+    for tier in (1024, 2048, 4096, 8192)
+]
+
+
+@pytest.mark.parametrize("hw,batch,band,tier", _VOTE_CASES)
+def test_hough_vote_compiles(one_chip, hw, batch, band, tier):
     H, W = hw
     cfg = HoughConfig()
     trig = hough_trig(H, W, cfg)
     if band is not None:
         trig = trig[:, 60 : 60 + band]
-    n_edges = ops.default_max_edges(H * W)
+    n_edges = tier or ops.default_max_edges(H * W)
 
     def step(xy, w):
         return hough_vote(xy, w, jnp.asarray(trig), n_rho=rho_bins(H, W, cfg))
